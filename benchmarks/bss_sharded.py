@@ -1,10 +1,13 @@
-"""Sharded BSS scaling sweep on a simulated host mesh.
+"""Sharded BSS scaling sweep over a device mesh.
 
     PYTHONPATH=src python -m benchmarks.bss_sharded --devices 1 2 4 8
 
-The forcing flag must precede jax initialisation, so the entry point
-re-executes itself in a subprocess with ``XLA_FLAGS`` requesting
-``max(devices)`` simulated host devices, then sweeps ONE built l2 index
+On an accelerator host the sweep runs in the calling process over the real
+devices (a chip belongs to one process: a child could not get it).  On a
+CPU-only host the devices are simulated; the forcing flag must precede jax
+initialisation, so the entry point then re-executes itself in a subprocess
+with ``XLA_FLAGS`` requesting ``max(devices)`` simulated host devices.
+Either way it sweeps ONE built l2 index
 through ``("data",)`` meshes of every requested width: the same range +
 kNN workload per width, hits AND per-query distance counts asserted
 against the numpy oracle and the single-device fused engine, wall-clock
@@ -151,9 +154,20 @@ def _sweep(devices, seed: int):
     return rows, results
 
 
+def _real_devices() -> bool:
+    """True when jax sees an accelerator: the sweep must then run in this
+    process, which holds the devices."""
+    import jax
+
+    return jax.default_backend() != "cpu"
+
+
 def run(devices=DEFAULT_DEVICES, seed: int = 0):
-    """Harness entry point (benchmarks.run): re-exec under the forcing
-    flag, then lift the child's CSV rows back into this process."""
+    """Harness entry point (benchmarks.run): in-process over real devices;
+    on CPU, re-exec under the forcing flag, then lift the child's CSV rows
+    back into this process."""
+    if _real_devices():
+        return _sweep(tuple(devices), seed)[0]
     out = _OUT
     code = _reexec_with_devices(tuple(devices), seed, out)
     if code != 0:
@@ -174,7 +188,7 @@ def main() -> None:
     ap.add_argument("--inner", action="store_true",
                     help="(internal) already under the forcing flag")
     args = ap.parse_args()
-    if not args.inner:
+    if not args.inner and not _real_devices():
         raise SystemExit(
             _reexec_with_devices(tuple(args.devices), args.seed, args.out)
         )

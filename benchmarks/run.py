@@ -4,15 +4,20 @@ engines.  Prints ``name,us_per_call,derived`` CSV (harness contract).
     PYTHONPATH=src python -m benchmarks.run            # all
     PYTHONPATH=src python -m benchmarks.run trees lrt  # subset
     REPRO_BENCH_FULL=1 ... for paper-size datasets (hours on 1 CPU core)
+
+A suite that raises still prints its ``<suite>/ERROR`` row, and the harness
+then exits non-zero.
 """
 
 from __future__ import annotations
 
 import sys
 from benchmarks.paper_common import now
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     from benchmarks import (
         analysis_cache,
         bss_engine,
@@ -45,6 +50,7 @@ def main() -> None:
     }
     pick = sys.argv[1:] or list(suites)
     print("name,us_per_call,derived")
+    failed = []
     for name in pick:
         t0 = now()
         try:
@@ -52,7 +58,10 @@ def main() -> None:
                 print(r, flush=True)
         except Exception as e:  # noqa: BLE001
             print(f"{name}/ERROR,0.0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
         print(f"# suite {name} finished in {now() - t0:.1f}s", flush=True)
+    if failed:
+        raise SystemExit(f"suites failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

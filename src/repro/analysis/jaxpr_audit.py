@@ -67,7 +67,7 @@ class AuditProblem:
 class _Capture:
     fn: str
     cell: str
-    closed: Any        # jax.core.ClosedJaxpr
+    closed: Any        # jax.extend.core.ClosedJaxpr
     out_shape: Any     # pytree of ShapeDtypeStruct
 
 
@@ -228,7 +228,7 @@ def _patched_engines(rec: _Recorder):
 
 def _sub_jaxprs(params: dict):
     """Yield every (Closed)Jaxpr buried in an eqn's params."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     def visit(v):
         if isinstance(v, jcore.ClosedJaxpr):
@@ -323,7 +323,7 @@ def _taint_jaxpr(jaxpr, in_taint: list[bool], consts=None) -> list[bool]:
     pallas_call included, which is sound because the only pallas kernels
     fed bf16 are the exact-phase distance scans whose outputs are
     legitimately tainted."""
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     tainted: set = set()
 
@@ -360,7 +360,7 @@ def eqns_of(jaxpr):
 
 
 def _eqn_out_taint(eqn, in_t: list[bool]) -> list[bool]:
-    import jax.core as jcore
+    import jax.extend.core as jcore
 
     name = eqn.primitive.name
     params = eqn.params
@@ -634,18 +634,25 @@ def run_audit(
 
             mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
             sidx = shard_index.shard_bss(idx, mesh)
-            for precision in ("fp32", "bf16"):
-                opts = EngineOpts(backend="jnp", precision=precision)
-                cell = f"sharded/{metric}/jnp/{precision}"
-                rec.cell = cell
-                log(f"audit {cell}")
-                shard_index.sharded_query_batched(
-                    sidx, q, t_narrow, opts=opts,
-                )
-                shard_index.sharded_knn_batched(sidx, q, 3, opts=opts)
-                problems += _audit_captures(
-                    rec, cell, bf16=precision == "bf16"
-                )
+            # both backends: the pallas leg traces the planes-major bound
+            # kernel and the scalar-prefetch masked kernels inside the
+            # jax.shard_map closures
+            for backend, interpret in (("jnp", None), ("pallas", True)):
+                for precision in ("fp32", "bf16"):
+                    opts = EngineOpts(
+                        backend=backend, interpret=interpret,
+                        precision=precision,
+                    )
+                    cell = f"sharded/{metric}/{backend}/{precision}"
+                    rec.cell = cell
+                    log(f"audit {cell}")
+                    shard_index.sharded_query_batched(
+                        sidx, q, t_narrow, opts=opts,
+                    )
+                    shard_index.sharded_knn_batched(sidx, q, 3, opts=opts)
+                    problems += _audit_captures(
+                        rec, cell, bf16=precision == "bf16"
+                    )
 
             # forest + monotone walkers
             tr = tree.build_tree("hpt_random_fixed", metric, db, seed=7)

@@ -71,11 +71,18 @@ def _sq_norms(x: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(x * x, axis=-1)
 
 
+def _dot_t(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
+    """``x @ y.T`` at fp32 accuracy: the TPU's DEFAULT f32 matmul is one
+    bf16 pass (~1e-3 relative error), which would move exact hits across a
+    threshold and loosen the planar bounds.  A no-op on CPU."""
+    return jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
+
+
 def _l2_pairwise(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     """||x-y|| via the matmul identity; fp32 accumulation; clamped at 0."""
     x = x.astype(jnp.float32)
     y = y.astype(jnp.float32)
-    sq = _sq_norms(x)[:, None] + _sq_norms(y)[None, :] - 2.0 * (x @ y.T)
+    sq = _sq_norms(x)[:, None] + _sq_norms(y)[None, :] - 2.0 * _dot_t(x, y)
     return jnp.sqrt(jnp.maximum(sq, 0.0))
 
 
@@ -90,7 +97,7 @@ def _cosine_pairwise(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:
     y = y.astype(jnp.float32)
     xn = x / jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), _EPS)
     yn = y / jnp.maximum(jnp.linalg.norm(y, axis=-1, keepdims=True), _EPS)
-    cos = jnp.clip(xn @ yn.T, -1.0, 1.0)
+    cos = jnp.clip(_dot_t(xn, yn), -1.0, 1.0)
     return jnp.sqrt(jnp.maximum(2.0 - 2.0 * cos, 0.0))
 
 

@@ -9,7 +9,8 @@ lower bound of §3) but restructures the computation for the MXU:
           pivot-pair planes; recursively median-split the *margin space* to
           find a locality-preserving permutation; group points into
           MXU-tile-aligned blocks of 128; store per (block × plane) bounding
-          boxes of the projected coordinates.
+          boxes of the projected coordinates, planes-major ``(4, M, B)`` so
+          the bound kernel reads lane-dense rows over blocks.
 
   query:  dist(q, pivots)  ->  project q onto all planes  ->  per block,
           lower-bound = max over planes of planar distance-to-box  ->
@@ -95,7 +96,7 @@ from jax.sharding import Mesh
 
 from repro.core import projection
 from repro.core.backends import EngineOpts, resolve_backend, resolve_engine_opts, tile_survival
-from repro.core.distances import Metric, get_metric
+from repro.core.distances import Metric, _dot_t, get_metric
 from repro.core.npdist import pairwise_np
 from repro.core.refpoints import select_fft
 from repro.kernels.pairwise_dist import (
@@ -103,7 +104,10 @@ from repro.kernels.pairwise_dist import (
     masked_pairwise_kernel_call,
     pairwise_kernel_call,
 )
-from repro.kernels.planar_exclusion import planar_lower_bound_kernel_call
+from repro.kernels.planar_exclusion import (
+    EMPTY_BOX,
+    planar_lower_bound_kernel_call,
+)
 from repro.kernels.tiles import TILE_BQ
 from repro.obs import schema as obs_schema
 
@@ -148,7 +152,7 @@ class BSSDeviceArrays(NamedTuple):
     pivots: jnp.ndarray  # (P, dim)
     pairs: jnp.ndarray   # (M, 2)
     deltas: jnp.ndarray  # (M,)
-    boxes: jnp.ndarray   # (n_blocks, M, 4)
+    boxes: jnp.ndarray   # (4, M, n_blocks) planes-major
     valid: jnp.ndarray   # (n_pad,) bool
 
 
@@ -161,7 +165,7 @@ class BSSIndex:
     pivots: np.ndarray        # (P, dim)
     pairs: np.ndarray         # (M, 2) pivot indices per plane
     deltas: np.ndarray        # (M,)
-    boxes: np.ndarray         # (n_blocks, M, 4) = x_lo, x_hi, y_lo, y_hi
+    boxes: np.ndarray         # (4, M, n_blocks): x_lo, x_hi, y_lo, y_hi rows
     block: int
     # build provenance + living-corpus bookkeeping (repro.index.maintain):
     # mutations are FUNCTIONAL — append/delete/compact return a new index
@@ -197,7 +201,7 @@ class BSSIndex:
 
     @property
     def n_blocks(self) -> int:
-        return self.boxes.shape[0]
+        return self.boxes.shape[2]
 
     @property
     def n_valid(self) -> int:
@@ -310,7 +314,9 @@ def _pack_blocks(
     """Pad already-permuted engine-space rows to whole blocks and compute
     the per (block × plane) bounding boxes — the packing half of
     ``build_bss``, shared with the append path so appended blocks are
-    bit-identical to built ones.  Returns ``(data_pad, valid, boxes)``."""
+    bit-identical to built ones.  Returns ``(data_pad, valid, boxes)`` with
+    ``boxes`` planes-major ``(4, M, n_blocks)``; an all-padding slot set
+    yields ``EMPTY_BOX`` (min=+big, max=-big: bound +inf)."""
     n, m = x.shape
     n_blocks = math.ceil(n / block)
     pad = n_blocks * block - n
@@ -325,17 +331,16 @@ def _pack_blocks(
     xs = xs.reshape(n_blocks, block, m)
     ys = ys.reshape(n_blocks, block, m)
     vmask = valid.reshape(n_blocks, block, 1)
-    big = np.float32(3.4e38)
+    x_lo, x_hi, y_lo, y_hi = (np.float32(v) for v in EMPTY_BOX)
     boxes = np.stack(
         [
-            np.where(vmask, xs, big).min(axis=1),
-            np.where(vmask, xs, -big).max(axis=1),
-            np.where(vmask, ys, big).min(axis=1),
-            np.where(vmask, ys, -big).max(axis=1),
+            np.where(vmask, xs, x_lo).min(axis=1),
+            np.where(vmask, xs, x_hi).max(axis=1),
+            np.where(vmask, ys, y_lo).min(axis=1),
+            np.where(vmask, ys, y_hi).max(axis=1),
         ],
-        axis=-1,
-    ).astype(np.float32)  # (n_blocks, M, 4)
-    return data_pad, valid, boxes
+    ).astype(np.float32)  # (4, n_blocks, M)
+    return data_pad, valid, np.ascontiguousarray(boxes.transpose(0, 2, 1))
 
 
 def build_bss(
@@ -576,9 +581,10 @@ def _fused_lower_bounds(
             d1, d2, dev_deltas, dev_boxes, bq=bq, interpret=interpret
         )
     qx, qy = projection.project(d1, d2, dev_deltas[None, :])  # (Q, M)
-    # (Q, 1, M) vs boxes (1, B, M, 4) -> per-plane bound, max over planes.
-    lb = projection.point_to_box(qx[:, None, :], qy[:, None, :], dev_boxes[None])
-    return jnp.max(lb, axis=-1)  # (Q, B)
+    # (Q, M, 1) vs planes-major boxes (4, M, B) -> per-plane bound (Q, M, B),
+    # max over planes.
+    lb = projection.point_to_box(qx[:, :, None], qy[:, :, None], dev_boxes)
+    return jnp.max(lb, axis=1)  # (Q, B)
 
 
 def _masked_exact_dists(
@@ -716,7 +722,7 @@ def _dense_hit_mask_jit(
     if metric_name == "l2":
         qf = queries.astype(jnp.float32)
         df = data.astype(jnp.float32)
-        s = -2.0 * (qf @ df.T) + jnp.sum(df * df, axis=-1)[None, :]
+        s = -2.0 * _dot_t(qf, df) + jnp.sum(df * df, axis=-1)[None, :]
         # t < 0 must hit nothing even though t*t > 0: send its threshold
         # to -inf (the squared-domain rearrangement is sign-blind).
         thresh = jnp.where(

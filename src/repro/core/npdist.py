@@ -110,6 +110,14 @@ def _resolve(name: str):
     return fn
 
 
+# metrics evaluated as an (n, m, dim) float64 broadcast; pairwise_np runs
+# them over row chunks so the temporary stays near _BROADCAST_ELEMS
+# elements (a 1M-row JSD build against 16 pivots would otherwise allocate
+# 8 GiB per temporary).  Row results do not depend on the chunking.
+_BROADCAST = ("jsd", "triangular", "l1", "linf")
+_BROADCAST_ELEMS = 1 << 24
+
+
 def pairwise_np(name: str, x: np.ndarray, y: np.ndarray) -> np.ndarray:
     fn = _resolve(name)
     x = np.asarray(x, np.float64)
@@ -118,6 +126,11 @@ def pairwise_np(name: str, x: np.ndarray, y: np.ndarray) -> np.ndarray:
         x = x[None, :]
     if y.ndim == 1:
         y = y[None, :]
+    step = max(1, _BROADCAST_ELEMS // max(1, y.shape[0] * y.shape[1]))
+    if name.partition("^")[0] in _BROADCAST and x.shape[0] > step:
+        return np.concatenate(
+            [fn(x[lo:lo + step], y) for lo in range(0, x.shape[0], step)]
+        )
     return fn(x, y)
 
 

@@ -115,11 +115,12 @@ def point_to_interval(v, lo, hi, *, xp=jnp):
 def point_to_box(x, y, box, *, xp=jnp):
     """Planar distance from point(s) to axis-aligned box(es).
 
-    ``box[..., :] = (x_lo, x_hi, y_lo, y_hi)``.  Broadcasts.  Because the
+    ``box[0..3] = (x_lo, x_hi, y_lo, y_hi)`` along the LEADING axis (the
+    BSS index's planes-major ``(4, M, B)`` table).  Broadcasts.  Because the
     planar metric lower-bounds the true metric, this is a sound lower bound
     on the distance from the query to EVERY point whose projection lies in
     the box — the Blocked Supermetric Scan's pruning primitive.
     """
-    dx = point_to_interval(x, box[..., 0], box[..., 1], xp=xp)
-    dy = point_to_interval(y, box[..., 2], box[..., 3], xp=xp)
+    dx = point_to_interval(x, box[0], box[1], xp=xp)
+    dy = point_to_interval(y, box[2], box[3], xp=xp)
     return xp.sqrt(dx * dx + dy * dy)

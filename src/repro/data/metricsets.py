@@ -23,6 +23,7 @@ __all__ = [
     "colors_surrogate",
     "nasa_surrogate",
     "topics_surrogate",
+    "sift_surrogate",
     "split_queries",
     "calibrate_threshold",
     "DATASETS",
@@ -107,6 +108,27 @@ def topics_surrogate(n: int = 24_576, dim: int = 64, seed: int = 0) -> np.ndarra
         o /= o.sum(axis=1, keepdims=True)
         pts[diffuse] = o
     return pts.astype(np.float64)
+
+
+def sift_surrogate(n: int = 1_000_000, dim: int = 128, seed: int = 0) -> np.ndarray:
+    """ANN-benchmarks ``sift-128-euclidean`` shape: 128-d non-negative
+    descriptor-like vectors, float32 (the deployment's storage dtype).
+
+    Mixture of 256 clusters with Zipf-skewed sizes around sparse gamma
+    centres, isotropic Gaussian spread with a per-cluster scale, clipped at
+    zero like gradient histograms.  Made in float32 throughout so 1M rows
+    generate in seconds."""
+    rng = np.random.default_rng(seed)
+    k = 256
+    centres = rng.gamma(0.6, 30.0, size=(k, dim)).astype(np.float32)
+    weights = 1.0 / np.arange(1, k + 1) ** 0.8
+    weights /= weights.sum()
+    scale = rng.lognormal(mean=2.0, sigma=0.3, size=k).astype(np.float32)
+    assign = rng.choice(k, size=n, p=weights)
+    pts = rng.standard_normal((n, dim), dtype=np.float32)
+    pts *= scale[assign, None]
+    pts += centres[assign]
+    return np.maximum(pts, 0.0, out=pts)
 
 
 def split_queries(

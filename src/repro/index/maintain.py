@@ -176,7 +176,7 @@ def append(
         data=np.concatenate([index.data, tail_data]),
         perm=np.concatenate([index.perm, tail_perm]),
         valid=np.concatenate([index.valid, tail_valid]),
-        boxes=np.concatenate([index.boxes, tail_boxes]),
+        boxes=np.concatenate([index.boxes, tail_boxes], axis=2),
         generation=index.generation + 1,
         next_id=index.next_id + m,
         _device=None,
@@ -198,7 +198,7 @@ def append(
             pairs=old.pairs,
             deltas=old.deltas,
             boxes=jnp.concatenate(
-                [old.boxes, jnp.asarray(tail_boxes, jnp.float32)]
+                [old.boxes, jnp.asarray(tail_boxes, jnp.float32)], axis=2
             ),
             valid=jnp.concatenate([old.valid, jnp.asarray(tail_valid)]),
         )
@@ -222,7 +222,7 @@ def append(
         table_dists=table_dists,
         n_blocks=new.n_blocks,
         tombstone_frac=new.tombstone_frac,
-        new_blocks=tail_boxes.shape[0],
+        new_blocks=tail_boxes.shape[2],
         sharded_in_place=sharded_in_place,
     )
 

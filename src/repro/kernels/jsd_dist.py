@@ -11,7 +11,7 @@ X tile and (bn, K) Y tile into VMEM and loops the pair reduction on the VPU.
 The x-entropy term depends only on x (resp. y) — precomputed per tile to
 avoid recomputing it bn (resp. bm) times.  The mixture-entropy broadcast is
 reduced in K-chunks of ``_K_CHUNK`` lanes so the (bm, bn, Kc) transient is
-bounded at 4 MiB even for 128x128 tiles (the BSS masked exact phase ties
+bounded at 8 MiB even for 128x128 tiles (the BSS masked exact phase ties
 bm/bn to the query-tile / block sizes) and large metric-space dims.
 
 Dtype-parametrised like the rest of the family (``pairwise_dist``, "Mixed
@@ -55,7 +55,11 @@ def _jsd_tile_kernel(x_ref, y_ref, o_ref):
     hy = jnp.sum(_xlogx(y), axis=1)  # (bn,)
     hm = jnp.zeros((x.shape[0], y.shape[0]), jnp.float32)  # (bm, bn)
     for k0 in range(0, k, _K_CHUNK):  # static K => unrolled at trace time
-        m = 0.5 * (x[:, None, k0 : k0 + _K_CHUNK] + y[None, :, k0 : k0 + _K_CHUNK])
+        # chunks are sliced from the REFS (2-D loads): the TPU compiler
+        # refuses a lane slice of the 3-D broadcast value
+        xs = x_ref[:, k0 : k0 + _K_CHUNK].astype(jnp.float32)
+        ys = y_ref[:, k0 : k0 + _K_CHUNK].astype(jnp.float32)
+        m = 0.5 * (xs[:, None, :] + ys[None, :, :])
         hm = hm + jnp.sum(_xlogx(m), axis=-1)
     js = 0.5 * hx[:, None] + 0.5 * hy[None, :] - hm
     o_ref[...] = jnp.sqrt(jnp.maximum(js, 0.0) / jnp.log(2.0))

@@ -18,7 +18,16 @@ VMEM budget per cell @ bm=bn=128, K=512, fp32:
 
 The masked variant consumes the BSS exclusion mask (one flag per output
 tile) and skips the MXU work of excluded tiles via ``pl.when`` — the planar
-lower bound of the paper materialised as *actually skipped* compute.
+lower bound of the paper materialised as *actually skipped* compute.  The
+mask travels as a scalar-prefetch operand: the TPU refuses a ``(1, 1)``
+VMEM block (the last two block dims must divide by (8, 128)), so the
+flags are bit-packed 32 per int32 word into SMEM — 4 x 7,813 tiles (512
+queries over 1M rows) is 977 words — and each grid step reads its own bit.
+
+The l2 contraction asks for ``Precision.HIGHEST``: the TPU's default f32
+matmul is one bf16 pass, whose ~1e-3 relative error would move hits across
+the threshold; HIGHEST gives fp32-accurate products on the MXU (and is a
+no-op on CPU).
 
 Metric-dispatched family
 ------------------------
@@ -55,6 +64,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.jsd_dist import _jsd_tile_kernel
 from repro.kernels.tiles import TILE_BLOCK, TILE_BQ
@@ -87,6 +97,7 @@ def _l2_tile_kernel(x_ref, y_ref, o_ref, *, squared: bool):
         x,
         y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     xx = jnp.sum(x * x, axis=1, keepdims=True)  # (bm, 1)  VPU
@@ -98,12 +109,28 @@ def _l2_tile_kernel(x_ref, y_ref, o_ref, *, squared: bool):
 def _masked_tile_kernel(mask_ref, x_ref, y_ref, o_ref, *, tile_kernel):
     """Metric-agnostic mask wrapper: the whole compute tile is skipped when
     the BSS planar lower bound already excluded this (query-tile, block)
-    cell — excluded tiles are filled with +inf without touching MXU/VPU."""
-    o_ref[...] = jnp.full_like(o_ref, jnp.inf)
+    cell — excluded tiles are filled with +inf without touching MXU/VPU.
+    ``mask_ref`` is the bit-packed flag vector in SMEM (``_pack_mask``)."""
+    flat = pl.program_id(0) * pl.num_programs(1) + pl.program_id(1)
+    live = (mask_ref[flat // 32] >> (flat % 32)) & 1
 
-    @pl.when(mask_ref[0, 0] != 0)
+    @pl.when(live == 0)
+    def _skip():
+        o_ref[...] = jnp.full_like(o_ref, jnp.inf)
+
+    @pl.when(live != 0)
     def _do():
         tile_kernel(x_ref, y_ref, o_ref)
+
+
+def _pack_mask(tile_mask: jnp.ndarray) -> jnp.ndarray:
+    """(m_tiles, n_tiles) flags -> row-major bit vector, 32 flags per int32
+    word (bit ``f % 32`` of word ``f // 32`` is flag ``f``)."""
+    flat = (tile_mask.reshape(-1) != 0).astype(jnp.uint32)
+    flat = _pad_to(flat, 32, 0).reshape(-1, 32)
+    shifts = jnp.arange(32, dtype=jnp.uint32)[None, :]
+    words = jnp.sum(flat << shifts, axis=1, dtype=jnp.uint32)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
 # metric name -> unmasked tile kernel (x_ref, y_ref, o_ref); the masked
@@ -167,16 +194,18 @@ def _masked_call(tile_kernel, x, y, tile_mask, *, bm, bn, interpret):
         )
     out = pl.pallas_call(
         functools.partial(_masked_tile_kernel, tile_kernel=tile_kernel),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, k), lambda i, j: (j, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((bm, k), lambda i, j, mask: (i, 0)),
+                pl.BlockSpec((bn, k), lambda i, j, mask: (j, 0)),
+            ],
+            out_specs=pl.BlockSpec((bm, bn), lambda i, j, mask: (i, j)),
+        ),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         interpret=interpret,
-    )(tile_mask.astype(jnp.int32), xp, yp)
+    )(_pack_mask(tile_mask), xp, yp)
     return out[:m, :n]
 
 

@@ -5,6 +5,7 @@ Tests sweep shapes/dtypes and assert_allclose kernel-vs-oracle.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -14,7 +15,7 @@ def pairwise_l2_ref(x: jnp.ndarray, y: jnp.ndarray, squared: bool = False) -> jn
     sq = (
         jnp.sum(x * x, axis=1)[:, None]
         + jnp.sum(y * y, axis=1)[None, :]
-        - 2.0 * (x @ y.T)
+        - 2.0 * jnp.matmul(x, y.T, precision=jax.lax.Precision.HIGHEST)
     )
     sq = jnp.maximum(sq, 0.0)
     return sq if squared else jnp.sqrt(sq)
@@ -43,12 +44,12 @@ def planar_lower_bound_ref(
         raw < DEGENERATE_DELTA, 0.0, (d1 * d1 - d2 * d2) / (2.0 * delta)
     )
     qy = jnp.sqrt(jnp.maximum(d1 * d1 - (qx + delta / 2.0) ** 2, 0.0))
-    qxe = qx[:, None, :]
-    qye = qy[:, None, :]
-    bx = boxes[None]
-    dx = jnp.maximum(jnp.maximum(bx[..., 0] - qxe, qxe - bx[..., 1]), 0.0)
-    dy = jnp.maximum(jnp.maximum(bx[..., 2] - qye, qye - bx[..., 3]), 0.0)
-    return jnp.max(jnp.sqrt(dx * dx + dy * dy), axis=-1)
+    # boxes are planes-major (4, M, B): per-plane bound (Q, M, B)
+    qxe = qx[:, :, None]
+    qye = qy[:, :, None]
+    dx = jnp.maximum(jnp.maximum(boxes[0] - qxe, qxe - boxes[1]), 0.0)
+    dy = jnp.maximum(jnp.maximum(boxes[2] - qye, qye - boxes[3]), 0.0)
+    return jnp.max(jnp.sqrt(dx * dx + dy * dy), axis=1)
 
 
 def pairwise_jsd_ref(x: jnp.ndarray, y: jnp.ndarray) -> jnp.ndarray:

@@ -2,15 +2,19 @@
 
 The fused engines tile their work as (query-tile x corpus-block) cells with
 a K-lane chunk bounding the VPU broadcast transient.  The defaults
-(128 / 128 / 64) match the MXU systolic array and the BSS block size, but
+(128 / 128 / 128) match the MXU systolic array, the BSS block size and the
+TPU's 128-lane vector width — every block meets the (8, 128) rule the TPU
+compiler enforces, and a K chunk narrower than 128 lanes is padded to 128
+anyway (a 64-lane chunk at K=128 ran out of scoped VMEM in a v5e compile);
+but
 real-TPU autotuning (see ROADMAP "Pallas masked-kernel autotuning") needs a
 way to try other shapes WITHOUT a rebuild — so each constant reads an
 environment variable at import time:
 
     REPRO_TILE_BQ      query-tile rows   (kernel bm / bq)      default 128
     REPRO_TILE_BLOCK   corpus-block cols (kernel bn / bb)      default 128
-    REPRO_TILE_KCHUNK  K lanes reduced per VPU pass            default 64
-    REPRO_TILE_VPU     standalone VPU-kernel tile (bm = bn)    default 64
+    REPRO_TILE_KCHUNK  K lanes reduced per VPU pass            default 128
+    REPRO_TILE_VPU     standalone VPU-kernel tile (bm = bn)    default 128
 
 This module is import-light on purpose (no jax): it must be readable by
 tooling/subprocesses without paying the jax import.  Consumers:
@@ -48,10 +52,10 @@ TILE_BLOCK = _env_int("REPRO_TILE_BLOCK", 128)
 
 # K lanes reduced per VPU pass in the broadcast-reduction tile kernels
 # (jsd / triangular); bounds the (bm, bn, Kc) VMEM transient
-TILE_KCHUNK = _env_int("REPRO_TILE_KCHUNK", 64)
+TILE_KCHUNK = _env_int("REPRO_TILE_KCHUNK", 128)
 
 # default square tile of the STANDALONE VPU kernels (the unmasked
-# jsd/triangular entry points, where the transcendental cost dominates and
-# a smaller tile keeps the broadcast transient cheap); the BSS masked
-# exact phase always overrides with bm=TILE_BQ / bn=TILE_BLOCK
-TILE_VPU = _env_int("REPRO_TILE_VPU", 64)
+# jsd/triangular entry points); 128 because the TPU refuses a 64-wide
+# output block (last dim must divide by 128); the BSS masked exact phase
+# always uses bm=TILE_BQ / bn=TILE_BLOCK
+TILE_VPU = _env_int("REPRO_TILE_VPU", 128)

@@ -6,7 +6,7 @@ pure-VPU broadcast reduction over probability vectors:
     d(x, y) = sqrt( 0.5 * sum_i (x_i - y_i)^2 / (x_i + y_i) )
 
 The (bm, bn, Kc) broadcast is reduced in K-chunks of ``_K_CHUNK`` lanes, so
-the VMEM transient never exceeds bm*bn*_K_CHUNK*4 bytes (4 MiB at 128x128
+the VMEM transient never exceeds bm*bn*_K_CHUNK*4 bytes (8 MiB at 128x128
 tiles) regardless of the metric-space dimension.
 
 Padding rows are all-zero: (0-0)^2 / max(0+0, eps) = 0, a valid input —
@@ -44,8 +44,10 @@ def _tri_tile_kernel(x_ref, y_ref, o_ref):
     k = x.shape[1]
     acc = jnp.zeros((x.shape[0], y.shape[0]), jnp.float32)
     for k0 in range(0, k, _K_CHUNK):  # static K => unrolled at trace time
-        xs = x[:, None, k0 : k0 + _K_CHUNK]
-        ys = y[None, :, k0 : k0 + _K_CHUNK]
+        # chunks are sliced from the REFS (2-D loads): the TPU compiler
+        # refuses a lane slice of the 3-D broadcast value
+        xs = x_ref[:, k0 : k0 + _K_CHUNK].astype(jnp.float32)[:, None, :]
+        ys = y_ref[:, k0 : k0 + _K_CHUNK].astype(jnp.float32)[None, :, :]
         num = (xs - ys) ** 2
         den = jnp.maximum(xs + ys, _EPS)
         acc = acc + jnp.sum(num / den, axis=-1)

@@ -395,7 +395,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, compile_: bool = True) -> di
                 idx_sds["deltas"] = jax.ShapeDtypeStruct(
                     (n_pairs,), jnp.float32)
                 idx_sds["boxes"] = jax.ShapeDtypeStruct(
-                    (b_blocks, n_pairs, 4), jnp.float32)
+                    (4, n_pairs, b_blocks), jnp.float32)
                 fwd = lambda p, b: model.forward_retrieval_pruned(  # noqa: E731
                     p, b, block=block, budget_blocks=3136)
                 opt_compiled = jax.jit(fwd).lower(params_sds, idx_sds).compile()

@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs.registry import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve.queue import now
 from repro.serve.retrieval import RetrievalServer
 
@@ -23,6 +24,7 @@ def main() -> None:
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--min-score", type=float, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     bundle = get_arch("two-tower-retrieval")
     model, cfg, _ = bundle.make_reduced()

@@ -264,7 +264,7 @@ class TwoTowerModel(_RecsysBase):
         """Supermetric-pruned candidate scoring (the paper's technique in the
         serving graph).  batch adds the BSS index arrays:
             pivots (P, E) fp32, pair_idx (M, 2) i32, deltas (M,) fp32,
-            boxes (B_blocks, M, 4) fp32.
+            boxes (4, M, B_blocks) fp32 (the index's planes-major table).
         Only the ``budget_blocks`` blocks with the smallest planar lower
         bound are gathered and scored — candidate HBM reads drop by
         B/budget (2.5x at the default).  Exact for any top-k whose k-th
@@ -277,7 +277,7 @@ class TwoTowerModel(_RecsysBase):
         piv = batch["pivots"]
         pairs = batch["pair_idx"]
         deltas = batch["deltas"]
-        boxes = batch["boxes"]  # (B_blocks, M, 4)
+        boxes = jnp.transpose(batch["boxes"], (2, 1, 0))  # (B_blocks, M, 4)
         n, e_dim = cand.shape
         b_blocks = boxes.shape[0]
         n_pad = b_blocks * block

@@ -37,7 +37,7 @@ gauges (``repro.obs.fold.shard_imbalance``).
 
 Block-count padding: when ``n_blocks`` is not a multiple of the shard
 count, empty padding blocks are appended — zero data rows marked invalid,
-and boxes carrying the same (min=+big, max=-big) empty-box sentinel a
+and boxes carrying the same ``EMPTY_BOX`` sentinel (min=+big, max=-big) a
 fully-padded block would get in ``build_bss``, so their planar bound is
 +inf and they are excluded at any finite radius.  All stats are reported
 over the REAL blocks only; results and per-query distance counts are
@@ -58,7 +58,6 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.backends import (
@@ -82,6 +81,7 @@ from repro.core.flat_index import (
     BSSDeviceArrays,
     BSSIndex,
 )
+from repro.kernels.planar_exclusion import EMPTY_BOX
 from repro.parallel.sharding import dp_axes, named
 
 __all__ = [
@@ -90,12 +90,6 @@ __all__ = [
     "sharded_query_batched",
     "sharded_knn_batched",
 ]
-
-# the empty-box sentinel build_bss uses for all-invalid slots: point_to_box
-# against (min=+big, max=-big) overflows to +inf in float32, so a padding
-# block is excluded by ANY finite radius
-_BIG = np.float32(3.4e38)
-
 
 def _shard_work(alive, valid_l, block):
     """Shard-local work summary, as functional jit outputs (shape (1,)
@@ -156,11 +150,10 @@ class ShardedBSSIndex:
                 [data, np.zeros((pad_b * block, dim), np.float32)]
             )
             valid = np.concatenate([valid, np.zeros(pad_b * block, bool)])
-            empty = np.tile(
-                np.array([_BIG, -_BIG, _BIG, -_BIG], np.float32),
-                (pad_b, m, 1),
+            empty = np.broadcast_to(
+                np.array(EMPTY_BOX, np.float32)[:, None, None], (4, m, pad_b)
             )
-            boxes = np.concatenate([boxes, empty])
+            boxes = np.concatenate([boxes, empty], axis=2)
             perm = np.concatenate(
                 [perm, np.full(pad_b * block, -1, np.int64)]
             )
@@ -176,7 +169,7 @@ class ShardedBSSIndex:
             pivots=put(jnp.asarray(index.pivots, jnp.float32), P()),
             pairs=put(jnp.asarray(index.pairs, jnp.int32), P()),
             deltas=put(jnp.asarray(index.deltas, jnp.float32), P()),
-            boxes=put(jnp.asarray(boxes, jnp.float32), P(axes, None, None)),
+            boxes=put(jnp.asarray(boxes, jnp.float32), P(None, None, axes)),
             valid=put(jnp.asarray(valid), P(axes)),
         )
         self._host_data = data  # padded layout, for the lazy bf16 mirror
@@ -220,17 +213,17 @@ class ShardedBSSIndex:
                 sdist, sblk = _shard_work(alive, valid_l, block)
                 return dist <= t[:, None], alive, tmask, sdist, sblk
 
-            self._fns[key] = jax.jit(shard_map(
-                local, self.mesh,
+            self._fns[key] = jax.jit(jax.shard_map(
+                local, mesh=self.mesh,
                 in_specs=(
-                    P(), P(), P(axes, None), P(axes), P(axes, None, None),
+                    P(), P(), P(axes, None), P(axes), P(None, None, axes),
                     P(), P(), P(),
                 ),
                 out_specs=(
                     P(None, axes), P(None, axes), P(None, axes),
                     P(axes), P(axes),
                 ),
-                check_rep=False,
+                check_vma=False,
             ))
         return self._fns[key]
 
@@ -274,17 +267,17 @@ class ShardedBSSIndex:
                     sdist, sblk,
                 )
 
-            self._fns[key] = jax.jit(shard_map(
-                local, self.mesh,
+            self._fns[key] = jax.jit(jax.shard_map(
+                local, mesh=self.mesh,
                 in_specs=(
                     P(), P(), P(), P(axes, None), P(axes),
-                    P(axes, None, None), P(), P(), P(), P(axes, None),
+                    P(None, None, axes), P(), P(), P(), P(axes, None),
                 ),
                 out_specs=(
                     P(None, axes), P(None, axes), P(None, axes),
                     P(None, axes), P(None, axes), P(axes), P(axes),
                 ),
-                check_rep=False,
+                check_vma=False,
             ))
         return self._fns[key]
 
@@ -299,11 +292,11 @@ class ShardedBSSIndex:
                     backend=backend, bq=bq, interpret=interpret,
                 )
 
-            self._fns[key] = jax.jit(shard_map(
-                local, self.mesh,
-                in_specs=(P(), P(axes, None, None), P(), P(), P()),
+            self._fns[key] = jax.jit(jax.shard_map(
+                local, mesh=self.mesh,
+                in_specs=(P(), P(None, None, axes), P(), P(), P()),
                 out_specs=P(None, axes),
-                check_rep=False,
+                check_vma=False,
             ))
         return self._fns[key]
 
@@ -341,8 +334,8 @@ class ShardedBSSIndex:
                 sdist, sblk = _shard_work(alive, valid_l, block)
                 return cand_idx, -neg2, alive, tmask, sdist, sblk
 
-            self._fns[key] = jax.jit(shard_map(
-                local, self.mesh,
+            self._fns[key] = jax.jit(jax.shard_map(
+                local, mesh=self.mesh,
                 in_specs=(
                     P(), P(), P(None, axes), P(axes, None), P(axes),
                 ),
@@ -350,7 +343,7 @@ class ShardedBSSIndex:
                     P(None, None), P(None, None), P(None, axes),
                     P(None, axes), P(axes), P(axes),
                 ),
-                check_rep=False,
+                check_vma=False,
             ))
         return self._fns[key]
 
@@ -414,8 +407,8 @@ class ShardedBSSIndex:
                     sdist, sblk,
                 )
 
-            self._fns[key] = jax.jit(shard_map(
-                local, self.mesh,
+            self._fns[key] = jax.jit(jax.shard_map(
+                local, mesh=self.mesh,
                 in_specs=(
                     P(), P(), P(), P(None, axes), P(axes, None), P(axes),
                     P(axes, None),
@@ -425,7 +418,7 @@ class ShardedBSSIndex:
                     P(None, axes), P(None, axes), P(None, axes),
                     P(axes), P(axes),
                 ),
-                check_rep=False,
+                check_vma=False,
             ))
         return self._fns[key]
 
@@ -444,14 +437,14 @@ class ShardedBSSIndex:
         return clone
 
     def _spliced(self, arr: jnp.ndarray, tail: np.ndarray, start: int,
-                 dtype) -> jnp.ndarray:
+                 dtype, axis: int = 0) -> jnp.ndarray:
         """Device-side in-place-style update of a sharded array (a fresh
         buffer, but updated ON the devices) with the output pinned to the
         array's own NamedSharding — the splice never gathers the corpus to
         one device and never re-lands the unchanged blocks."""
         fn = jax.jit(
             lambda a, b: jax.lax.dynamic_update_slice_in_dim(
-                a, b, start, 0
+                a, b, start, axis
             ),
             out_shardings=arr.sharding,
         )
@@ -476,7 +469,7 @@ class ShardedBSSIndex:
         engine hot.  Returns ``None`` when they do NOT fit — the caller
         falls back to a lazy full re-layout (the block count must grow,
         which moves every chunk boundary)."""
-        nb_new = tail_boxes.shape[0]
+        nb_new = tail_boxes.shape[2]
         free = self.n_blocks_pad - self.index.n_blocks
         if nb_new > free:
             return None
@@ -499,7 +492,7 @@ class ShardedBSSIndex:
             pairs=self.dev.pairs,
             deltas=self.dev.deltas,
             boxes=self._spliced(
-                self.dev.boxes, tail_boxes, start_blk, jnp.float32
+                self.dev.boxes, tail_boxes, start_blk, jnp.float32, axis=2
             ),
             valid=self._spliced(
                 self.dev.valid, tail_valid, start_row, jnp.bool_

@@ -17,7 +17,7 @@ from __future__ import annotations
 from typing import Any
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, get_abstract_mesh
 
 __all__ = [
     "P",
@@ -68,13 +68,6 @@ def abstract_with_sharding(shape_tree: Any, sharding_tree: Any) -> Any:
 
 
 def _no_active_mesh() -> bool:
-    try:
-        from jax.sharding import get_abstract_mesh
-    except ImportError:
-        # jax <= 0.4.37: the `with mesh:` context lives in thread_resources
-        from jax.interpreters import pxla
-
-        return pxla.thread_resources.env.physical_mesh.empty
     m = get_abstract_mesh()
     return m is None or m.empty
 

@@ -49,8 +49,10 @@ def test_planar_lower_bound_sweep(q, m, b):
     d2 = jnp.asarray(np.abs(d1 + rng.normal(size=(q, m)) * 0.2), jnp.float32)
     lo = rng.normal(size=(b, m, 2))
     hi = lo + np.abs(rng.normal(size=(b, m, 2)))
+    # planes-major (4, M, B), the index's box layout
     boxes = jnp.asarray(
-        np.stack([lo[..., 0], hi[..., 0], lo[..., 1], hi[..., 1]], -1), jnp.float32
+        np.stack([lo[..., 0], hi[..., 0], lo[..., 1], hi[..., 1]]).transpose(0, 2, 1),
+        jnp.float32,
     )
     got = ops.planar_lower_bound(d1, d2, delta, boxes, interpret=True)
     want = ref.planar_lower_bound_ref(d1, d2, delta, boxes)
