@@ -251,9 +251,10 @@ print(
 #     monotonic clock.  export_trace() writes Chrome trace-event JSON:
 #     open it at https://ui.perfetto.dev (or chrome://tracing) and each
 #     request is its own track, with mutations inline on the driver
-#     track.  Building the front with profile_dir="..." additionally
-#     wraps each engine dispatch in a jax.profiler trace so device-level
-#     profiles line up with these host-side spans.  On a sharded index
+#     track.  The dispatch and engine phases are also profiler
+#     annotations: wrap any stretch of serving in jax.profiler.trace(...)
+#     and they appear on its host plane beside the device operations,
+#     on the same clock as ServeResult.batch.spans.  On a sharded index
 #     the same stats carry per-shard work splits — the shard/imbalance
 #     gauge in render() (max/mean, 1.0 = perfectly balanced) is the row a
 #     rebalancing policy would watch.

@@ -95,7 +95,13 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.core import projection
-from repro.core.backends import EngineOpts, resolve_backend, resolve_engine_opts, tile_survival
+from repro.core.backends import (
+    EngineOpts,
+    jit_cache_size,
+    resolve_backend,
+    resolve_engine_opts,
+    tile_survival,
+)
 from repro.core.distances import Metric, _dot_t, get_metric
 from repro.core.npdist import pairwise_np
 from repro.core.refpoints import select_fft
@@ -110,6 +116,7 @@ from repro.kernels.planar_exclusion import (
 )
 from repro.kernels.tiles import TILE_BQ
 from repro.obs import schema as obs_schema
+from repro.obs.spans import SpanLog
 
 __all__ = [
     "BSSIndex",
@@ -926,6 +933,51 @@ def _finish_stats(stats: dict, *, kind: str, backend: str,
     )
 
 
+def _jit_cache_sizes() -> dict:
+    return {name: jit_cache_size(fn) for name, fn in ENGINE_JITS.items()}
+
+
+class _CallRecord:
+    """The host side of one engine call: its ``engine/<kind>/*`` spans
+    (``repro.obs.spans.SpanLog``), the bytes it copies from the device and
+    the compiles it causes.  Every device-to-host copy of the call goes
+    through :meth:`fetch`.  :meth:`finish` puts the three in ``stats`` as
+    ``spans``, ``d2h_bytes`` and ``compiles``."""
+
+    __slots__ = ("log", "d2h_bytes", "_device", "_d2h", "_sizes")
+
+    def __init__(self, kind: str):
+        self.log = SpanLog()
+        self.d2h_bytes = 0
+        self._device = f"engine/{kind}/device"
+        self._d2h = f"engine/{kind}/d2h"
+        self._sizes = _jit_cache_sizes()
+
+    def fetch(self, *arrays):
+        """Copy device arrays to numpy, counting their bytes: first wait
+        for the device to produce them (``engine/<kind>/device``), so the
+        copy's own span (``engine/<kind>/d2h``) holds no device time."""
+        with self.log.span(self._device):
+            jax.block_until_ready(arrays)
+        with self.log.span(self._d2h):
+            out = [np.asarray(a) for a in arrays]
+        self.d2h_bytes += sum(a.nbytes for a in out)
+        return out[0] if len(out) == 1 else out
+
+    def finish(self, stats: dict) -> dict:
+        """``compiles`` names each engine jit that gained cache entries
+        during the call (in this process: a concurrent call's compiles
+        count too) with how many."""
+        after = _jit_cache_sizes()
+        stats["spans"] = self.log.records
+        stats["d2h_bytes"] = int(self.d2h_bytes)
+        stats["compiles"] = {
+            name: after[name] - self._sizes[name] for name in after
+            if self._sizes[name] >= 0 and after[name] > self._sizes[name]
+        }
+        return stats
+
+
 def bss_query_batched(
     index: BSSIndex,
     queries: np.ndarray,
@@ -1001,6 +1053,7 @@ def bss_query_batched(
     metric_eng = _engine_metric(index.metric_name)
     queries = _engine_queries(index.metric_name, np.asarray(queries, np.float32))
     nq = queries.shape[0]
+    call = _CallRecord("range")
     if nq == 0:
         stats = _batched_stats(
             index,
@@ -1010,80 +1063,96 @@ def bss_query_batched(
         stats["precision"] = precision
         if precision == "bf16":
             _bf16_stats(stats, index.bf16_margin(), 0, np.zeros(0, np.int64))
-        return [], _finish_stats(stats, kind="range", backend=backend)
+        return [], call.finish(
+            _finish_stats(stats, kind="range", backend=backend)
+        )
     t_vec = _per_query_t(t, nq)
     dev = index.device
     if precision == "bf16":
         return _query_batched_bf16(
-            index, metric_eng, queries, t_vec, dev,
+            index, metric_eng, queries, t_vec, dev, call,
             bq=bq, backend=backend, interpret=interpret,
             realisation=realisation,
         )
     if backend == "jnp":
-        qj = jnp.asarray(queries)
-        lb = np.asarray(
-            _lower_bounds_jit(
-                metric_eng, qj, dev.pivots, dev.pairs, dev.deltas,
-                dev.boxes,
+        with call.log.span("engine/range/launch"):
+            qj = jnp.asarray(queries)
+            lb = _lower_bounds_jit(
+                metric_eng, qj, dev.pivots, dev.pairs, dev.deltas, dev.boxes,
             )
-        )
-        alive = lb <= t_vec[:, None]
-        if realisation == "dense" or alive.mean() > _DENSE_ALIVE_FRAC:
-            mask = np.asarray(
-                _dense_hit_mask_jit(
+        lb = call.fetch(lb)
+        with call.log.span("engine/range/launch"):
+            alive = lb <= t_vec[:, None]
+            dense = realisation == "dense" or alive.mean() > _DENSE_ALIVE_FRAC
+            if dense:
+                mask = _dense_hit_mask_jit(
                     metric_eng, qj, dev.data, dev.valid,
                     jnp.asarray(alive), jnp.asarray(t_vec), block=index.block,
                 )
-            )
-            hit_q, hit_pos = np.nonzero(mask)  # (query, position) ascending
+            else:
+                qidx, bidx = np.nonzero(alive)  # sorted by (query, block)
+                c = len(qidx)
+                c_pad = _next_pow2(c)
+                cell_valid = jnp.asarray(np.arange(c_pad) < c)
+                qidx_p = jnp.asarray(np.pad(qidx, (0, c_pad - c)), jnp.int32)
+                bidx_p = jnp.asarray(np.pad(bidx, (0, c_pad - c)), jnp.int32)
+                cap = _next_pow2(8 * max(nq, 1), lo=1024)
+        if dense:
+            mask = call.fetch(mask)
         else:
-            qidx, bidx = np.nonzero(alive)  # sorted by (query, block)
-            c = len(qidx)
-            c_pad = _next_pow2(c)
-            cell_valid = jnp.asarray(np.arange(c_pad) < c)
-            qidx_p = jnp.asarray(np.pad(qidx, (0, c_pad - c)), jnp.int32)
-            bidx_p = jnp.asarray(np.pad(bidx, (0, c_pad - c)), jnp.int32)
-            cap = _next_pow2(8 * max(nq, 1), lo=1024)
             while True:
-                hit_q, hit_pos, n_hits = _cells_exact_jit(
-                    metric_eng, qj, dev.data, dev.valid,
-                    qidx_p, bidx_p, cell_valid, jnp.asarray(t_vec),
-                    block=index.block, cap=cap,
-                )
-                n_hits = int(n_hits)
+                with call.log.span("engine/range/launch"):
+                    outs = _cells_exact_jit(
+                        metric_eng, qj, dev.data, dev.valid,
+                        qidx_p, bidx_p, cell_valid, jnp.asarray(t_vec),
+                        block=index.block, cap=cap,
+                    )
+                n_hits = int(call.fetch(outs[2]))
                 if n_hits <= cap:
                     break
                 cap = _next_pow2(n_hits)  # rare: recompile, bigger bucket
-            hit_q = np.asarray(hit_q)[:n_hits]
-            hit_pos = np.asarray(hit_pos)[:n_hits]
-        orig = index.perm[hit_pos]
-        counts = np.bincount(hit_q, minlength=nq)
+            hit_q, hit_pos = call.fetch(outs[0], outs[1])
+        with call.log.span("engine/range/select"):
+            if dense:
+                hit_q, hit_pos = np.nonzero(mask)  # (query, position) ascending
+            else:
+                hit_q, hit_pos = hit_q[:n_hits], hit_pos[:n_hits]
+            orig = index.perm[hit_pos]
+            counts = np.bincount(hit_q, minlength=nq)
+            per_query = np.split(orig, np.cumsum(counts)[:-1])
+            results = [r.tolist() for r in per_query]
+        with call.log.span("engine/range/stats"):
+            tile_mask = call.fetch(_tile_survival(jnp.asarray(alive), bq))
+            stats = _batched_stats(index, alive, tile_mask)
+            stats["precision"] = "fp32"
+        return results, call.finish(
+            _finish_stats(stats, kind="range", backend=backend)
+        )
+    with call.log.span("engine/range/launch"):
+        outs = _query_batched_jit(
+            metric_eng,
+            jnp.asarray(queries),
+            jnp.asarray(t_vec),
+            dev,
+            block=index.block,
+            bq=bq,
+            backend=backend,
+            interpret=interpret,
+        )
+    dist, alive, tile_mask = call.fetch(*outs)
+    with call.log.span("engine/range/select"):
+        hit = dist <= t_vec[:, None]
+        qidx, pidx = np.nonzero(hit)  # row-major: pidx ascending within a query
+        orig = index.perm[pidx]
+        counts = hit.sum(axis=1)
         per_query = np.split(orig, np.cumsum(counts)[:-1])
         results = [r.tolist() for r in per_query]
-        tile_mask = np.asarray(_tile_survival(jnp.asarray(alive), bq))
+    with call.log.span("engine/range/stats"):
         stats = _batched_stats(index, alive, tile_mask)
         stats["precision"] = "fp32"
-        return results, _finish_stats(stats, kind="range", backend=backend)
-    dist, alive, tile_mask = _query_batched_jit(
-        metric_eng,
-        jnp.asarray(queries),
-        jnp.asarray(t_vec),
-        dev,
-        block=index.block,
-        bq=bq,
-        backend=backend,
-        interpret=interpret,
+    return results, call.finish(
+        _finish_stats(stats, kind="range", backend=backend)
     )
-    dist = np.asarray(dist)
-    hit = dist <= t_vec[:, None]
-    qidx, pidx = np.nonzero(hit)  # row-major: pidx ascending within a query
-    orig = index.perm[pidx]
-    counts = hit.sum(axis=1)
-    per_query = np.split(orig, np.cumsum(counts)[:-1])
-    results = [r.tolist() for r in per_query]
-    stats = _batched_stats(index, np.asarray(alive), np.asarray(tile_mask))
-    stats["precision"] = "fp32"
-    return results, _finish_stats(stats, kind="range", backend=backend)
 
 
 def _bf16_stats(stats: dict, eps: float, recheck_tiles: int,
@@ -1109,6 +1178,7 @@ def _query_batched_bf16(
     queries: np.ndarray,
     t_vec: np.ndarray,
     dev: BSSDeviceArrays,
+    call: _CallRecord,
     *,
     bq: int,
     backend: str,
@@ -1124,42 +1194,43 @@ def _query_batched_bf16(
     qj = jnp.asarray(queries)
     eps_j = jnp.float32(eps)
     if backend == "jnp" and realisation != "dense":
-        lb = np.asarray(
-            _lower_bounds_jit(
+        with call.log.span("engine/range/launch"):
+            lb = _lower_bounds_jit(
                 metric_eng, qj, dev.pivots, dev.pairs, dev.deltas, dev.boxes,
             )
-        )
+        lb = call.fetch(lb)
         alive = lb <= t_vec[:, None]
         # Same adaptive branch condition as fp32 (it reads only the fp32
         # bound phase), so both precisions pick the same realisation.
         if alive.mean() <= _DENSE_ALIVE_FRAC:
-            qidx, bidx = np.nonzero(alive)  # sorted by (query, block)
-            c = len(qidx)
-            c_pad = _next_pow2(c)
-            qidx_p = np.pad(qidx, (0, c_pad - c)).astype(np.int32)
-            bidx_p = np.pad(bidx, (0, c_pad - c)).astype(np.int32)
-            cell_valid = jnp.asarray(np.arange(c_pad) < c)
-            tj = jnp.asarray(t_vec)
-            cap = _next_pow2(8 * max(nq, 1), lo=1024)
+            with call.log.span("engine/range/launch"):
+                qidx, bidx = np.nonzero(alive)  # sorted by (query, block)
+                c = len(qidx)
+                c_pad = _next_pow2(c)
+                qidx_p = np.pad(qidx, (0, c_pad - c)).astype(np.int32)
+                bidx_p = np.pad(bidx, (0, c_pad - c)).astype(np.int32)
+                cell_valid = jnp.asarray(np.arange(c_pad) < c)
+                tj = jnp.asarray(t_vec)
+                cap = _next_pow2(8 * max(nq, 1), lo=1024)
             while True:
-                hit_q, hit_pos, n_hits, band_cell, band_counts = (
-                    _cells_exact_bf16_jit(
+                with call.log.span("engine/range/launch"):
+                    outs = _cells_exact_bf16_jit(
                         metric_eng, qj, data16, dev.valid,
                         jnp.asarray(qidx_p), jnp.asarray(bidx_p),
                         cell_valid, tj, eps_j,
                         block=index.block, cap=cap,
                     )
-                )
-                n_hits = int(n_hits)
+                n_hits = int(call.fetch(outs[2]))
                 if n_hits <= cap:
                     break
                 cap = _next_pow2(n_hits)
-            hit_q = np.asarray(hit_q)[:n_hits]
-            hit_pos = np.asarray(hit_pos)[:n_hits]
-            band_counts = np.asarray(band_counts)
+            hit_q, hit_pos, band_cell, band_counts = call.fetch(
+                outs[0], outs[1], outs[3], outs[4]
+            )
+            hit_q, hit_pos = hit_q[:n_hits], hit_pos[:n_hits]
             # fp32 re-check of every band CELL through the fp32 engine's own
             # sparse realisation — values and hit masks bit-identical to it.
-            band_cells = np.nonzero(np.asarray(band_cell))[0]
+            band_cells = np.nonzero(band_cell)[0]
             if band_cells.size:
                 q2 = qidx_p[band_cells]
                 b2 = bidx_p[band_cells]
@@ -1167,47 +1238,57 @@ def _query_batched_bf16(
                 c2_pad = _next_pow2(c2)
                 cap2 = _next_pow2(8 * max(nq, 1), lo=1024)
                 while True:
-                    rq, rp, n_r = _cells_exact_jit(
-                        metric_eng, qj, dev.data, dev.valid,
-                        jnp.asarray(np.pad(q2, (0, c2_pad - c2)), jnp.int32),
-                        jnp.asarray(np.pad(b2, (0, c2_pad - c2)), jnp.int32),
-                        jnp.asarray(np.arange(c2_pad) < c2), tj,
-                        block=index.block, cap=cap2,
-                    )
-                    n_r = int(n_r)
+                    with call.log.span("engine/range/launch"):
+                        outs = _cells_exact_jit(
+                            metric_eng, qj, dev.data, dev.valid,
+                            jnp.asarray(np.pad(q2, (0, c2_pad - c2)), jnp.int32),
+                            jnp.asarray(np.pad(b2, (0, c2_pad - c2)), jnp.int32),
+                            jnp.asarray(np.arange(c2_pad) < c2), tj,
+                            block=index.block, cap=cap2,
+                        )
+                    n_r = int(call.fetch(outs[2]))
                     if n_r <= cap2:
                         break
                     cap2 = _next_pow2(n_r)
-                hit_q = np.concatenate([hit_q, np.asarray(rq)[:n_r]])
-                hit_pos = np.concatenate([hit_pos, np.asarray(rp)[:n_r]])
-                order = np.lexsort((hit_pos, hit_q))
-                hit_q = hit_q[order]
-                hit_pos = hit_pos[order]
-            orig = index.perm[hit_pos]
-            counts = np.bincount(hit_q, minlength=nq)
-            per_query = np.split(orig, np.cumsum(counts)[:-1])
-            results = [r.tolist() for r in per_query]
-            tile_mask = np.asarray(_tile_survival(jnp.asarray(alive), bq))
-            stats = _batched_stats(index, alive, tile_mask)
-            _bf16_stats(stats, eps, 0, band_counts)
-            return results, _finish_stats(
-                stats, kind="range", backend=backend
+                rq, rp = call.fetch(outs[0], outs[1])
+            with call.log.span("engine/range/select"):
+                if band_cells.size:
+                    hit_q = np.concatenate([hit_q, rq[:n_r]])
+                    hit_pos = np.concatenate([hit_pos, rp[:n_r]])
+                    order = np.lexsort((hit_pos, hit_q))
+                    hit_q = hit_q[order]
+                    hit_pos = hit_pos[order]
+                orig = index.perm[hit_pos]
+                counts = np.bincount(hit_q, minlength=nq)
+                per_query = np.split(orig, np.cumsum(counts)[:-1])
+                results = [r.tolist() for r in per_query]
+            with call.log.span("engine/range/stats"):
+                tile_mask = call.fetch(
+                    _tile_survival(jnp.asarray(alive), bq)
+                )
+                stats = _batched_stats(index, alive, tile_mask)
+                _bf16_stats(stats, eps, 0, band_counts)
+            return results, call.finish(
+                _finish_stats(stats, kind="range", backend=backend)
             )
-    hit, alive, tile_mask, recheck_tiles, band_counts = (
-        _query_batched_bf16_jit(
+    with call.log.span("engine/range/launch"):
+        outs = _query_batched_bf16_jit(
             metric_eng, qj, jnp.asarray(t_vec), dev, data16, eps_j,
             block=index.block, bq=bq, backend=backend, interpret=interpret,
         )
+    hit, alive, tile_mask, recheck_tiles, band_counts = call.fetch(*outs)
+    with call.log.span("engine/range/select"):
+        hit_q, hit_pos = np.nonzero(hit)  # row-major: positions ascending
+        orig = index.perm[hit_pos]
+        counts = hit.sum(axis=1)
+        per_query = np.split(orig, np.cumsum(counts)[:-1])
+        results = [r.tolist() for r in per_query]
+    with call.log.span("engine/range/stats"):
+        stats = _batched_stats(index, alive, tile_mask)
+        _bf16_stats(stats, eps, int(recheck_tiles), band_counts)
+    return results, call.finish(
+        _finish_stats(stats, kind="range", backend=backend)
     )
-    hit = np.asarray(hit)
-    hit_q, hit_pos = np.nonzero(hit)  # row-major: positions ascending
-    orig = index.perm[hit_pos]
-    counts = hit.sum(axis=1)
-    per_query = np.split(orig, np.cumsum(counts)[:-1])
-    results = [r.tolist() for r in per_query]
-    stats = _batched_stats(index, np.asarray(alive), np.asarray(tile_mask))
-    _bf16_stats(stats, eps, int(recheck_tiles), np.asarray(band_counts))
-    return results, _finish_stats(stats, kind="range", backend=backend)
 
 
 @partial(
@@ -1410,6 +1491,24 @@ def _knn_lb_jit(
     )
 
 
+# Every jitted function of the engine, under the name its compiles are
+# counted by: each call's ``stats["compiles"]`` and the serving front's
+# ``compile/cache_size`` / ``compile/recompiles`` series read this one list.
+ENGINE_JITS = {
+    "range/lb": _lower_bounds_jit,
+    "range/dense": _dense_hit_mask_jit,
+    "range/cells": _cells_exact_jit,
+    "range/fused": _query_batched_jit,
+    "range/bf16": _query_batched_bf16_jit,
+    "range/cells_bf16": _cells_exact_bf16_jit,
+    "knn/lb": _knn_lb_jit,
+    "knn/round": _knn_round_jit,
+    "knn/round_bf16": _knn_round_bf16_jit,
+    "knn/round_cells": _knn_round_cells_jit,
+    "knn/round_cells_bf16": _knn_round_cells_bf16_jit,
+}
+
+
 def _knn_empty_stats(index: BSSIndex, nq: int, precision: str,
                      backend: str, engine: str = "bss") -> dict:
     """Schema-conformant stats for the kNN early returns (no queries, or
@@ -1523,11 +1622,12 @@ def bss_knn_batched(
     k = int(k)
     if k <= 0:
         raise ValueError(f"k must be positive, got {k}")
+    call = _CallRecord("knn")
     if nq == 0:
         return (
             np.zeros((0, k), np.int64),
             np.zeros((0, k), np.float32),
-            _knn_empty_stats(index, 0, precision, backend),
+            call.finish(_knn_empty_stats(index, 0, precision, backend)),
         )
     # clamp to the VALID corpus size: with k_run > n_valid the kth distance
     # would stay inf and no round could ever finish early
@@ -1536,7 +1636,7 @@ def bss_knn_batched(
         return (
             np.full((nq, k), -1, np.int64),
             np.full((nq, k), np.inf, np.float32),
-            _knn_empty_stats(index, nq, precision, backend),
+            call.finish(_knn_empty_stats(index, nq, precision, backend)),
         )
     dev = index.device
     qj = jnp.asarray(queries)
@@ -1551,17 +1651,18 @@ def bss_knn_batched(
     # (through the selected backend) and reuse across every round — the
     # device copy feeds the rounds, the sorted host copy drives the initial
     # radius and the per-round widening schedule.
-    lb_dev = _knn_lb_jit(
-        metric_eng, qj, dev, bq=bq, backend=backend, interpret=interpret
-    )
-    lb_np = np.asarray(lb_dev)
-    lb_sorted = np.sort(lb_np, axis=1)
     n_blocks = index.n_blocks
-    if r0 is None:
-        j0 = min(n_blocks - 1, max(0, math.ceil(2 * k / index.block) - 1))
-        radii = lb_sorted[:, j0].astype(np.float32)
-    else:
-        radii = np.full(nq, float(r0), np.float32)
+    with call.log.span("engine/knn/bounds"):
+        lb_dev = _knn_lb_jit(
+            metric_eng, qj, dev, bq=bq, backend=backend, interpret=interpret
+        )
+        lb_np = call.fetch(lb_dev)
+        lb_sorted = np.sort(lb_np, axis=1)
+        if r0 is None:
+            j0 = min(n_blocks - 1, max(0, math.ceil(2 * k / index.block) - 1))
+            radii = lb_sorted[:, j0].astype(np.float32)
+        else:
+            radii = np.full(nq, float(r0), np.float32)
 
     valid_pb = _valid_per_block(index)
     total_exact = np.zeros(nq, np.int64)
@@ -1572,134 +1673,139 @@ def bss_knn_batched(
     cand_dist = np.full((nq, k_run), np.inf, np.float32)
     rounds = 0
     for rounds in range(1, max_rounds + 2):
-        if rounds == max_rounds + 1:
-            # exhaustive fallback for stragglers: radius inf computes every
-            # block, so the round below is guaranteed final for them.
-            radii = np.where(done, radii, np.inf).astype(np.float32)
-        alive_host = lb_np <= radii[:, None]  # identical to the device test
-        if (backend == "jnp" and realisation != "dense"
-                and alive_host.mean() <= _DENSE_ALIVE_FRAC):
-            # sparse round: gather only the alive cells (adaptive, like the
-            # range path; the branch condition reads only the fp32 bound
-            # phase, so both precisions take it identically);
-            # done/alive/tiles derived on host
-            qidx, bidx = np.nonzero(alive_host)
-            c = len(qidx)
-            c_pad = _next_pow2(c)
-            qidx_p = np.pad(qidx, (0, c_pad - c)).astype(np.int32)
-            bidx_p = np.pad(bidx, (0, c_pad - c)).astype(np.int32)
-            if bf16:
-                # bf16 scan picks the band cells; the UNCHANGED fp32 round
-                # below then runs over just those cells — its values, tie
-                # order and outputs are exactly the fp32 round's.
-                band_cell, band_counts = _knn_round_cells_bf16_jit(
-                    metric_eng, qj, data16, dev.valid,
+        with call.log.span("engine/knn/round", round=rounds):
+            if rounds == max_rounds + 1:
+                # exhaustive fallback for stragglers: radius inf computes
+                # every block, so the round below is guaranteed final for
+                # them.
+                radii = np.where(done, radii, np.inf).astype(np.float32)
+            alive_host = lb_np <= radii[:, None]  # identical to the device test
+            if (backend == "jnp" and realisation != "dense"
+                    and alive_host.mean() <= _DENSE_ALIVE_FRAC):
+                # sparse round: gather only the alive cells (adaptive, like
+                # the range path; the branch condition reads only the fp32
+                # bound phase, so both precisions take it identically);
+                # done/alive/tiles derived on host
+                qidx, bidx = np.nonzero(alive_host)
+                c = len(qidx)
+                c_pad = _next_pow2(c)
+                qidx_p = np.pad(qidx, (0, c_pad - c)).astype(np.int32)
+                bidx_p = np.pad(bidx, (0, c_pad - c)).astype(np.int32)
+                if bf16:
+                    # bf16 scan picks the band cells; the UNCHANGED fp32
+                    # round below then runs over just those cells — its
+                    # values, tie order and outputs are exactly the fp32
+                    # round's.
+                    outs = _knn_round_cells_bf16_jit(
+                        metric_eng, qj, data16, dev.valid,
+                        jnp.asarray(qidx_p), jnp.asarray(bidx_p),
+                        jnp.asarray(np.arange(c_pad) < c), eps_j,
+                        k=k_run, block=index.block,
+                    )
+                    band_cell, band_counts = call.fetch(*outs)
+                    sel = np.nonzero(band_cell)[0]
+                    recheck_pq += np.where(~done, band_counts, 0)
+                    qidx_p, bidx_p = qidx_p[sel], bidx_p[sel]
+                    c = len(sel)
+                    c_pad = _next_pow2(c)
+                    qidx_p = np.pad(qidx_p, (0, c_pad - c)).astype(np.int32)
+                    bidx_p = np.pad(bidx_p, (0, c_pad - c)).astype(np.int32)
+                outs = _knn_round_cells_jit(
+                    metric_eng, qj, dev.data, dev.valid,
                     jnp.asarray(qidx_p), jnp.asarray(bidx_p),
-                    jnp.asarray(np.arange(c_pad) < c), eps_j,
+                    jnp.asarray(np.arange(c_pad) < c),
                     k=k_run, block=index.block,
                 )
-                sel = np.nonzero(np.asarray(band_cell))[0]
-                recheck_pq += np.where(~done, np.asarray(band_counts), 0)
-                qidx_p, bidx_p = qidx_p[sel], bidx_p[sel]
-                c = len(sel)
-                c_pad = _next_pow2(c)
-                qidx_p = np.pad(qidx_p, (0, c_pad - c)).astype(np.int32)
-                bidx_p = np.pad(bidx_p, (0, c_pad - c)).astype(np.int32)
-            ci, cd = _knn_round_cells_jit(
-                metric_eng, qj, dev.data, dev.valid,
-                jnp.asarray(qidx_p), jnp.asarray(bidx_p),
-                jnp.asarray(np.arange(c_pad) < c),
-                k=k_run, block=index.block,
-            )
-            ci, cd = np.asarray(ci), np.asarray(cd)
-            kth = cd[:, -1]
-            dn = np.isfinite(kth) & (
-                (kth <= radii) | alive_host.all(axis=1)
-            )
-            alive = alive_host
-            tiles_round = int(
-                np.asarray(_tile_survival(jnp.asarray(alive_host), bq)).sum()
-            )
-        elif bf16:
-            (ci, cd, kth, dn, alive, tile_mask, rtiles, band_counts) = (
-                _knn_round_bf16_jit(
+                ci, cd = call.fetch(*outs)
+                kth = cd[:, -1]
+                dn = np.isfinite(kth) & (
+                    (kth <= radii) | alive_host.all(axis=1)
+                )
+                alive = alive_host
+                tiles_round = int(call.fetch(
+                    _tile_survival(jnp.asarray(alive_host), bq)
+                ).sum())
+            elif bf16:
+                outs = _knn_round_bf16_jit(
                     metric_eng, qj, jnp.asarray(radii), lb_dev, dev,
                     data16, eps_j,
                     k=k_run, block=index.block, bq=bq, backend=backend,
                     interpret=interpret,
                 )
-            )
-            ci, cd, kth, dn, alive = (
-                np.asarray(ci), np.asarray(cd), np.asarray(kth),
-                np.asarray(dn), np.asarray(alive),
-            )
-            tiles_round = int(np.asarray(tile_mask).sum())
-            recheck_tiles_total += int(rtiles)
-            recheck_pq += np.where(~done, np.asarray(band_counts), 0)
-        else:
-            ci, cd, kth, dn, alive, tile_mask = _knn_round_jit(
-                metric_eng, qj, jnp.asarray(radii), lb_dev, dev,
-                k=k_run, block=index.block, bq=bq, backend=backend,
-                interpret=interpret,
-            )
-            ci, cd, kth, dn, alive = (
-                np.asarray(ci), np.asarray(cd), np.asarray(kth),
-                np.asarray(dn), np.asarray(alive),
-            )
-            tiles_round = int(np.asarray(tile_mask).sum())
-        upd = ~done  # freeze finished queries (their results are final)
-        cand_idx[upd] = ci[upd]
-        cand_dist[upd] = cd[upd]
-        total_exact[upd] += alive[upd].astype(np.int64) @ valid_pb
-        excl_pq[upd] += n_blocks - alive[upd].sum(axis=1)
-        tiles_total += tiles_round
-        done = done | dn
-        if done.all():
-            break
-        # widen to the radius that (at least) doubles the surviving blocks,
-        # tighten by the kth-nearest-so-far where we already hold k
-        # candidates — min() keeps the next mask as small as evidence allows.
-        n_alive = alive.sum(axis=1)
-        j_next = np.minimum(
-            n_blocks - 1,
-            np.maximum(np.maximum(2 * n_alive, n_alive + 1), 1),
-        )
-        widened = np.maximum(lb_sorted[np.arange(nq), j_next], radii * growth)
-        # finished queries get a negative radius: lb >= 0, so their alive
-        # rows empty out and they stop contributing blocks/tiles to the
-        # remaining rounds (their results are already frozen above)
-        radii = np.where(
-            done, np.float32(-1.0),
-            np.where(np.isfinite(kth), np.minimum(kth, widened), widened),
-        ).astype(np.float32)
-        # unprunable query (most blocks already alive): grinding more
-        # rounds just re-evaluates them — finish exhaustively instead
-        radii = np.where(
-            ~done & (n_alive > n_blocks // 2), np.float32(np.inf), radii
-        )
+                (ci, cd, kth, dn, alive, tile_mask, rtiles,
+                 band_counts) = call.fetch(*outs)
+                tiles_round = int(tile_mask.sum())
+                recheck_tiles_total += int(rtiles)
+                recheck_pq += np.where(~done, band_counts, 0)
+            else:
+                outs = _knn_round_jit(
+                    metric_eng, qj, jnp.asarray(radii), lb_dev, dev,
+                    k=k_run, block=index.block, bq=bq, backend=backend,
+                    interpret=interpret,
+                )
+                ci, cd, kth, dn, alive, tile_mask = call.fetch(*outs)
+                tiles_round = int(tile_mask.sum())
+            with call.log.span("engine/knn/schedule"):
+                upd = ~done  # freeze finished queries (their results are final)
+                cand_idx[upd] = ci[upd]
+                cand_dist[upd] = cd[upd]
+                total_exact[upd] += alive[upd].astype(np.int64) @ valid_pb
+                excl_pq[upd] += n_blocks - alive[upd].sum(axis=1)
+                tiles_total += tiles_round
+                done = done | dn
+                if done.all():
+                    break
+                # widen to the radius that (at least) doubles the surviving
+                # blocks, tighten by the kth-nearest-so-far where we already
+                # hold k candidates — min() keeps the next mask as small as
+                # evidence allows.
+                n_alive = alive.sum(axis=1)
+                j_next = np.minimum(
+                    n_blocks - 1,
+                    np.maximum(np.maximum(2 * n_alive, n_alive + 1), 1),
+                )
+                widened = np.maximum(
+                    lb_sorted[np.arange(nq), j_next], radii * growth
+                )
+                # finished queries get a negative radius: lb >= 0, so their
+                # alive rows empty out and they stop contributing
+                # blocks/tiles to the remaining rounds (their results are
+                # already frozen above)
+                radii = np.where(
+                    done, np.float32(-1.0),
+                    np.where(np.isfinite(kth), np.minimum(kth, widened),
+                             widened),
+                ).astype(np.float32)
+                # unprunable query (most blocks already alive): grinding
+                # more rounds just re-evaluates them — finish exhaustively
+                radii = np.where(
+                    ~done & (n_alive > n_blocks // 2), np.float32(np.inf),
+                    radii,
+                )
 
-    n_pivots = index.pivots.shape[0]
-    stats = {
-        "rounds": rounds,
-        "pivot_dists_per_query": float(n_pivots),
-        "exact_dists_per_query": float(total_exact.mean()),
-        "dists_per_query": float(n_pivots + total_exact.mean()),
-        "per_query_dists": n_pivots + total_exact,
-        "tiles_computed": tiles_total,
-        "n_blocks": int(index.n_blocks),
-        "generation": int(index.generation),
-        "precision": precision,
-        # rounds x blocks the Hilbert bound pruned from the exact phase,
-        # accumulated per query over its unfinished rounds only
-        "excluded": {"hilbert": excl_pq},
-    }
-    if bf16:
-        _bf16_stats(stats, eps, recheck_tiles_total, recheck_pq)
-    _finish_stats(stats, kind="knn", backend=backend)
-    orig = np.where(np.isfinite(cand_dist), index.perm[cand_idx], -1)
-    if k_run < k:  # corpus smaller than k: pad out to the requested width
-        orig = np.pad(orig, ((0, 0), (0, k - k_run)), constant_values=-1)
-        cand_dist = np.pad(
-            cand_dist, ((0, 0), (0, k - k_run)), constant_values=np.inf
-        )
-    return orig, cand_dist, stats
+    with call.log.span("engine/knn/stats"):
+        n_pivots = index.pivots.shape[0]
+        stats = {
+            "rounds": rounds,
+            "pivot_dists_per_query": float(n_pivots),
+            "exact_dists_per_query": float(total_exact.mean()),
+            "dists_per_query": float(n_pivots + total_exact.mean()),
+            "per_query_dists": n_pivots + total_exact,
+            "tiles_computed": tiles_total,
+            "n_blocks": int(index.n_blocks),
+            "generation": int(index.generation),
+            "precision": precision,
+            # rounds x blocks the Hilbert bound pruned from the exact phase,
+            # accumulated per query over its unfinished rounds only
+            "excluded": {"hilbert": excl_pq},
+        }
+        if bf16:
+            _bf16_stats(stats, eps, recheck_tiles_total, recheck_pq)
+        _finish_stats(stats, kind="knn", backend=backend)
+        orig = np.where(np.isfinite(cand_dist), index.perm[cand_idx], -1)
+        if k_run < k:  # corpus smaller than k: pad out to the requested width
+            orig = np.pad(orig, ((0, 0), (0, k - k_run)), constant_values=-1)
+            cand_dist = np.pad(
+                cand_dist, ((0, 0), (0, k - k_run)), constant_values=np.inf
+            )
+    return orig, cand_dist, call.finish(stats)

@@ -21,6 +21,15 @@ materialised anyway, so observability adds no synchronisation points and
 cannot change results (the bit-identity test in ``tests/test_obs.py``
 proves it).
 
+The host side of each call is timed where it runs, outside any jit: the
+engines' host drivers, the serving front and the retrieval server record
+``SpanLog`` spans on the serving clock (``stats["spans"]``,
+``ServeResult.batch``), count every device-to-host copy's bytes
+(``stats["d2h_bytes"]``) and the compiles a call causes
+(``stats["compiles"]``).  The engine's one added wait, a
+``block_until_ready`` before the copy that would wait anyway, splits
+device time from copy time.
+
 Layout
 ------
 - ``registry`` — counters / gauges / bounded-ring histograms with real
@@ -30,7 +39,9 @@ Layout
   overrides used by every histogram
 - ``schema`` — the shared engine-stats schema + validator, and
   ``METRIC_NAMES``, the one registry of runtime metric names (lint R6)
-- ``spans`` — per-request trace ids and monotonic stage timestamps
+- ``spans`` — per-request trace ids and monotonic stage timestamps, and
+  ``SpanLog``: the host phases of one engine / dispatch / server call on
+  the same clock, each also a ``jax.profiler.TraceAnnotation``
 - ``trace`` — Chrome trace-event JSON (Perfetto) export of spans, engine
   phases, and mutation events, all on the serving clock
 - ``fold`` — stats -> registry at the jit boundary; compile-cache polling
@@ -57,7 +68,7 @@ from repro.obs.schema import (
     normalise_stats,
     validate_stats,
 )
-from repro.obs.spans import STAGES, Span, new_trace_id
+from repro.obs.spans import STAGES, Span, SpanLog, new_trace_id
 from repro.obs.trace import (
     TraceBuffer,
     complete_event,
@@ -81,6 +92,7 @@ __all__ = [
     "SCHEMA_VERSION",
     "STAGES",
     "Span",
+    "SpanLog",
     "TraceBuffer",
     "check_stats",
     "complete_event",

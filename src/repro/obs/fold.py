@@ -91,6 +91,9 @@ def fold_engine_stats(reg: MetricsRegistry, stats: dict) -> None:
                 int(stats["recheck_tiles"])
             )
 
+    if "d2h_bytes" in stats:
+        reg.counter("engine/d2h_bytes", **lbl).inc(int(stats["d2h_bytes"]))
+
     if kind == "knn" and "rounds" in stats:
         reg.histogram("engine/knn_rounds", **lbl).observe(
             int(stats["rounds"])

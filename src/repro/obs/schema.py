@@ -24,6 +24,20 @@ key                     meaning
                         the walkers)
 ======================  =====================================================
 
+Optional keys, type-checked when present — the host side of the call
+(``repro.obs.spans``; the BSS engine's single-device paths fill them):
+
+======================  =====================================================
+``spans``               list of ``(name, start, end, parent)`` — the call's
+                        host phases on the serving clock (``engine/<kind>/*``;
+                        ``RetrievalServer.search`` adds its ``server/search``
+                        as their root); ``parent`` is a row index or None
+``d2h_bytes``           int — bytes of every device array the call copied to
+                        the host
+``compiles``            dict jit name -> int — new compile-cache entries
+                        during the call, for each engine jit that gained any
+======================  =====================================================
+
 Engine-specific keys (``n_blocks``, ``tiles_computed``, ``n_levels``,
 ``frontier_occupancy``, ``rounds``, the bf16 band keys, the sharded
 engine's ``shard_dists`` / ``shard_blocks`` per-shard work vectors, ...)
@@ -83,6 +97,7 @@ METRIC_NAMES = {
     "engine/recheck_points",
     "engine/recheck_tiles",
     "engine/knn_rounds",
+    "engine/d2h_bytes",
     # sharded-engine work split (fold_engine_stats on sharded stats)
     "shard/dists",
     "shard/blocks",
@@ -217,7 +232,60 @@ def validate_stats(stats) -> list:
                 problems.append(f"precision=bf16 but missing {k!r}")
     if stats["kind"] == "knn" and "rounds" not in stats:
         problems.append("kind=knn but missing 'rounds'")
+    if "spans" in stats:
+        problems.extend(_span_problems(stats["spans"]))
+    if "d2h_bytes" in stats and not _is_count(stats["d2h_bytes"]):
+        problems.append(
+            f"d2h_bytes={stats['d2h_bytes']!r} is not a non-negative int"
+        )
+    if "compiles" in stats:
+        c = stats["compiles"]
+        if not isinstance(c, dict) or not all(
+            isinstance(k, str) and _is_count(v) for k, v in c.items()
+        ):
+            problems.append(
+                f"compiles={c!r} is not a dict of name -> non-negative int"
+            )
     return problems
+
+
+def _is_count(v) -> bool:
+    return isinstance(v, (int, np.integer)) and not isinstance(v, bool) \
+        and v >= 0
+
+
+def _span_problems(spans) -> list:
+    """Problems of a ``spans`` list: rows ``(name, start, end, parent)``
+    with a string name, ``start <= end``, and a parent that is an earlier
+    row (or None) whose interval holds the row's."""
+    if not isinstance(spans, (list, tuple)):
+        return [f"spans is {type(spans).__name__}, expected list"]
+    out = []
+    for i, row in enumerate(spans):
+        if not (isinstance(row, (tuple, list)) and len(row) == 4):
+            out.append(f"spans[{i}] is not a (name, start, end, parent) tuple")
+            continue
+        name, start, end, parent = row
+        if not isinstance(name, str) or not name:
+            out.append(f"spans[{i}] has no name")
+        if not all(isinstance(x, (float, int)) for x in (start, end)) \
+                or not start <= end:
+            out.append(f"spans[{i}] {name!r}: bad interval ({start}, {end})")
+            continue
+        if parent is None:
+            continue
+        if not (isinstance(parent, int) and 0 <= parent < i):
+            out.append(f"spans[{i}] {name!r}: parent {parent!r} is not an "
+                       f"earlier row")
+            continue
+        try:
+            inside = spans[parent][1] <= start and end <= spans[parent][2]
+        except (TypeError, IndexError):
+            inside = False
+        if not inside:
+            out.append(f"spans[{i}] {name!r} lies outside its parent "
+                       f"{spans[parent][0]!r}")
+    return out
 
 
 def check_stats(stats) -> dict:

@@ -11,11 +11,12 @@ on the driver track, and mutation slices on the same track so index
 maintenance shows up inline with the traffic it stalls.
 
 Timestamps are microseconds on the monotonic clock, so host spans line
-up with each other exactly; when the front also runs under
-``profile_dir=`` it wraps each engine call in a
-``jax.profiler.TraceAnnotation`` named after the dispatch, so the
-device-side profile carries the same dispatch names and the two
-timelines can be read side by side.
+up with each other exactly.  The dispatch and engine phases come from
+``repro.obs.spans.SpanLog`` records, which are also
+``jax.profiler.TraceAnnotation`` spans of the same names: inside any
+``jax.profiler.trace`` the device-side profile carries them too, and
+one anchor annotation at a known ``now()`` maps the profiler's epoch
+onto this clock.
 
 ``validate_trace`` is the schema check CI and tests use — no Perfetto
 binary needed.

@@ -80,12 +80,20 @@ Engine knobs ride one frozen :class:`~repro.core.backends.EngineOpts`
 Host-side by design (and recorded as such in the ROADMAP): the queue, the
 driver thread, the cache and the demux all run in numpy/threading; only
 the engine call inside ``_dispatch`` touches jax.
+
+Every dispatch records its host phases (``repro.obs.spans.SpanLog``):
+``dispatch``, holding ``dispatch/assemble``, ``dispatch/engine`` (the
+engine's own ``engine/*`` spans nest under it) and ``dispatch/demux``.
+They ride every row's ``ServeResult.batch``, feed the ``dispatch/*``
+trace-buffer events, and — being ``jax.profiler.TraceAnnotation`` spans
+too — appear in any ``jax.profiler.trace`` an operator wraps around a
+stretch of serving.
 """
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
+import itertools
 import threading
 from collections import OrderedDict, deque
 from concurrent.futures import Future
@@ -115,7 +123,7 @@ from repro.obs.fold import (
     shard_imbalance as _shard_imbalance,
 )
 from repro.obs.registry import MetricsRegistry
-from repro.obs.spans import Span
+from repro.obs.spans import Span, SpanLog
 from repro.obs.trace import (
     TraceBuffer,
     complete_event,
@@ -131,7 +139,30 @@ from repro.serve.queue import (
     now,
 )
 
-__all__ = ["ServingFront", "ServeResult", "ShedError"]
+__all__ = ["BatchRecord", "ServingFront", "ServeResult", "ShedError"]
+
+_batch_ids = itertools.count(1)
+
+
+@dataclasses.dataclass(frozen=True)
+class BatchRecord:
+    """One dispatched micro-batch, shared (not copied) by every row it
+    served.
+
+    ``spans`` is the dispatch's ``SpanLog`` rows — ``(name, start, end,
+    parent)`` on the serving clock: ``dispatch`` and its children
+    ``dispatch/assemble``, ``dispatch/engine`` (with the engine's
+    ``engine/*`` spans under it) and ``dispatch/demux``.  The rows'
+    futures resolve inside ``dispatch/demux``, so that span and
+    ``dispatch`` read ``end`` None until the dispatch returns a moment
+    later.  ``d2h_bytes`` and ``compiles`` are the engine call's
+    (``stats["d2h_bytes"]`` / ``stats["compiles"]``), None where the
+    engine does not count them."""
+
+    id: int                               # process-unique, increasing
+    spans: list
+    d2h_bytes: int | None
+    compiles: dict | None
 
 
 @dataclasses.dataclass
@@ -152,6 +183,7 @@ class ServeResult:
     generation: int = 0                  # index snapshot this was served on
     trace_id: str = ""                   # obs trace id (front.explain(...))
     spans: dict | None = None            # per-stage durations (obs spans)
+    batch: BatchRecord | None = None     # the micro-batch (None: cache hit)
 
 
 def _copy_result(res: ServeResult) -> ServeResult:
@@ -269,7 +301,6 @@ class ServingFront:
         prep=None,
         start: bool = True,
         metrics: bool = True,
-        profile_dir: str | None = None,
     ):
         if isinstance(index, flat_index.BSSIndex):
             self._engine = "bss"
@@ -322,25 +353,15 @@ class ServingFront:
         self._waits: deque[float] = deque(maxlen=4096)
         self._engine_s_total = 0.0
         # observability: registry folding + explain ring are gated on
-        # `metrics`; trace ids and span timestamps always ride the requests
-        # (they are part of ServeResult).  `profile_dir` opts into a
-        # jax.profiler.trace around each engine dispatch.
+        # `metrics`; trace ids, span timestamps and the batch record always
+        # ride the requests (they are part of ServeResult)
         self.metrics_enabled = bool(metrics)
-        self.profile_dir = profile_dir
         self._metrics = MetricsRegistry()
         self._trace = TraceBuffer()
         self._explain: deque[dict] = deque(maxlen=256)
         self._compile_last: dict[str, int] = {}
         if self._engine == "bss":
-            self._compile_watch = {
-                "range/lb": flat_index._lower_bounds_jit,
-                "range/dense": flat_index._dense_hit_mask_jit,
-                "range/fused": flat_index._query_batched_jit,
-                "range/bf16": flat_index._query_batched_bf16_jit,
-                "knn/lb": flat_index._knn_lb_jit,
-                "knn/round": flat_index._knn_round_jit,
-                "knn/round_bf16": flat_index._knn_round_bf16_jit,
-            }
+            self._compile_watch = flat_index.ENGINE_JITS
         elif isinstance(index, EncodedMonotone):
             self._compile_watch = {
                 "forest/monotone_walk": forest_walk._monotone_walk_jit,
@@ -505,7 +526,7 @@ class ServingFront:
                     self._metrics.counter("serve/cache_hits").inc()
                 fut.set_result(dataclasses.replace(
                     hit, cache_hit=True, trace_id=span.trace_id,
-                    spans=span.durations(),
+                    spans=span.durations(), batch=None,
                 ))
                 return fut
         req = Request(
@@ -562,29 +583,6 @@ class ServingFront:
         except Exception:  # noqa: BLE001 — cancel racing the set
             return False
 
-    def _profiler(self):
-        """Opt-in ``jax.profiler.trace`` context around one dispatch (a
-        no-op unless the front was built with ``profile_dir=``).  Host-side
-        only — it wraps the engine call, it never reaches into the jit."""
-        if self.profile_dir is None:
-            return contextlib.nullcontext()
-        import jax
-
-        return jax.profiler.trace(self.profile_dir)
-
-    def _annotate(self, name: str):
-        """Opt-in ``jax.profiler.TraceAnnotation`` around the engine call.
-
-        The annotation name carries the dispatch's span timestamp on the
-        serving clock, so the device-side profile and the host trace
-        (``export_trace``) can be lined up on one timeline even though the
-        profiler keeps its own epoch."""
-        if self.profile_dir is None:
-            return contextlib.nullcontext()
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-
     def _dispatch(self, group: list[Request]) -> None:
         """One engine call for one compatible micro-batch: pad to the
         bucket, run the fused path, demux rows to futures."""
@@ -599,60 +597,91 @@ class ServingFront:
         group = [r for r in group if not r.future.cancelled()]
         if not group:
             return
-        t_batch = now()
-        for r in group:
-            if r.span is not None:
-                r.span.mark("batch", t_batch)
         n = len(group)
         bucket = bucket_for(n, self.buckets)
         pad = bucket - n
-        qs = np.stack([r.query for r in group])
-        if pad:
-            # duplicate the first row: always a valid engine input (zeros
-            # are not, for the probability-space metrics); BSS range pads
-            # are additionally killed by their -1 radius below
-            qs = np.concatenate([qs, np.repeat(qs[:1], pad, axis=0)])
-        if self.prep is not None:
-            qs = self.prep(qs)
         head = group[0]
-        t_wait = now()
-        for r in group:
-            if r.span is not None:
-                r.span.mark("dispatch", t_wait)
-        # one EngineOpts per dispatch: the front's base knobs with this
-        # group's precision overlaid (precisions never share a batch)
-        eng_opts = dataclasses.replace(self.opts, precision=head.precision)
-        ann = (
-            f"serve/engine kind={head.kind} bucket={bucket} "
-            f"gen={generation} t_dispatch={t_wait:.6f}"
-        )
-        with self._profiler(), self._annotate(ann):
-            if head.kind == "range" and self._engine == "bss":
-                t_vec = np.array(
-                    [r.t for r in group] + [-1.0] * pad, np.float32
+        log = SpanLog()
+        with log.span("dispatch", kind=head.kind, bucket=bucket,
+                      generation=generation):
+            with log.span("dispatch/assemble") as assemble:
+                qs = np.stack([r.query for r in group])
+                if pad:
+                    # duplicate the first row: always a valid engine input
+                    # (zeros are not, for the probability-space metrics);
+                    # BSS range pads are additionally killed by their -1
+                    # radius below
+                    qs = np.concatenate(
+                        [qs, np.repeat(qs[:1], pad, axis=0)]
+                    )
+                if self.prep is not None:
+                    qs = self.prep(qs)
+                # one EngineOpts per dispatch: the front's base knobs with
+                # this group's precision overlaid (precisions never share a
+                # batch)
+                eng_opts = dataclasses.replace(
+                    self.opts, precision=head.precision
                 )
-                hits, stats = flat_index.bss_query_batched(
-                    index, qs, t_vec, opts=eng_opts,
-                )
-            elif head.kind == "range":  # forest: scalar-t walker
-                search = (
-                    monotone_range_search
-                    if isinstance(index, EncodedMonotone)
-                    else forest_range_search
-                )
-                hits, stats = search(
-                    index, qs, head.t, self.mechanism, opts=eng_opts,
-                )
-            else:  # knn
-                _, k, r0, max_rounds, _ = head.group
-                idx, dist, stats = flat_index.bss_knn_batched(
-                    index, qs, k, r0=r0, max_rounds=max_rounds,
-                    opts=eng_opts,
-                )
-        t_engine = now()
+            with log.span("dispatch/engine") as engine:
+                if head.kind == "range" and self._engine == "bss":
+                    t_vec = np.array(
+                        [r.t for r in group] + [-1.0] * pad, np.float32
+                    )
+                    out, stats = flat_index.bss_query_batched(
+                        index, qs, t_vec, opts=eng_opts,
+                    )
+                elif head.kind == "range":  # forest: scalar-t walker
+                    search = (
+                        monotone_range_search
+                        if isinstance(index, EncodedMonotone)
+                        else forest_range_search
+                    )
+                    out, stats = search(
+                        index, qs, head.t, self.mechanism, opts=eng_opts,
+                    )
+                else:  # knn
+                    _, k, r0, max_rounds, _ = head.group
+                    idx, dist, stats = flat_index.bss_knn_batched(
+                        index, qs, k, r0=r0, max_rounds=max_rounds,
+                        opts=eng_opts,
+                    )
+                    out = (idx, dist)
+                log.adopt(stats.get("spans", ()))
+            with log.span("dispatch/demux"):
+                self._demux(group, bucket, generation, stats, out,
+                            assemble, engine, log)
+        if self.metrics_enabled:
+            # one clock for everything: the dispatch's phases (and the
+            # engine's) land on the driver track (tid 0), each request's
+            # stage slices on its own per-request track — all stamped by
+            # `now()`
+            args = {
+                "kind": head.kind, "batch_size": n, "padded_to": bucket,
+                "generation": generation,
+                "engine": str(stats.get("engine", self._engine)),
+                "n_dists": int(np.asarray(stats["per_query_dists"])[:n].sum()),
+            }
+            self._trace.extend(
+                complete_event(name, start, end - start, tid=0,
+                               cat=name.split("/", 1)[0], args=args)
+                for name, start, end, _ in log.records
+            )
+
+    def _demux(self, group, bucket, generation, stats, out,
+               assemble, engine, log) -> None:
+        """Fold the batch's telemetry and resolve every row's future with
+        its row of the engine output ``out``: hit lists (range), or
+        (indices, distances) (kNN).  ``assemble`` and ``engine`` are the
+        dispatch's closed spans, ``log`` its span log."""
+        n = len(group)
+        pad = bucket - n
+        head = group[0]
+        t_batch, t_wait, t_engine = assemble.start, engine.start, engine.end
         engine_s = t_engine - t_wait
         for r in group:
             if r.span is not None:
+                r.span.mark("batch", t_batch)
+                r.span.mark("dispatch", t_wait)
                 r.span.mark("engine", t_engine)
         per_q = np.asarray(stats["per_query_dists"])
         excluded = {
@@ -693,6 +722,11 @@ class ServingFront:
                 # bucket artefact, not precision cost
                 self._n["bf16_rows"] += n
                 self._n["recheck_points"] += int(recheck[:n].sum())
+        batch = BatchRecord(
+            id=next(_batch_ids), spans=log.records,
+            d2h_bytes=stats.get("d2h_bytes"),
+            compiles=stats.get("compiles"),
+        )
         trace_evs: list[dict] = []
         for i, r in enumerate(group):
             wait = t_wait - r.t_submit
@@ -711,12 +745,13 @@ class ServingFront:
                 queue_wait_s=wait,
                 engine_s=engine_s, batch_size=n, padded_to=bucket,
                 generation=generation, trace_id=r.trace_id, spans=durs,
+                batch=batch,
             )
             if r.kind == "range":
-                res.hits = hits[i]
+                res.hits = out[i]
             else:
-                res.indices = idx[i]
-                res.distances = dist[i]
+                res.indices = out[0][i]
+                res.distances = out[1][i]
             if self.metrics_enabled:
                 if durs:
                     for stage, v in durs.items():
@@ -757,25 +792,7 @@ class ServingFront:
                 self._waits.append(wait)
                 if self._cache is not None and r.cache_key is not None:
                     self._cache.put(r.cache_key, res)
-        if self.metrics_enabled:
-            # one clock for everything: the dispatch's engine-phase slices
-            # land on the driver track (tid 0), each request's stage slices
-            # on its own per-request track — all stamped by `now()`
-            args = {
-                "kind": head.kind, "batch_size": n, "padded_to": bucket,
-                "generation": generation,
-                "engine": str(stats.get("engine", self._engine)),
-                "n_dists": int(per_q[:n].sum()),
-            }
-            trace_evs.extend([
-                complete_event("dispatch/assemble", t_batch,
-                               t_wait - t_batch, tid=0, cat="dispatch",
-                               args=args),
-                complete_event("dispatch/engine", t_wait, engine_s, tid=0,
-                               cat="dispatch", args=args),
-                complete_event("dispatch/demux", t_engine, now() - t_engine,
-                               tid=0, cat="dispatch", args=args),
-            ])
+        if trace_evs:
             self._trace.extend(trace_evs)
 
     # ------------------------------------------------------------ mutations

@@ -67,6 +67,7 @@ from repro.forest import encode_tree, forest_range_search
 from repro.index import maintain as index_maintain
 from repro.obs.fold import fold_engine_stats, fold_mutation
 from repro.obs.registry import MetricsRegistry
+from repro.obs.spans import SpanLog
 from repro.serve.queue import now
 
 __all__ = ["RetrievalServer", "SearchResult", "score_to_distance",
@@ -208,42 +209,46 @@ class RetrievalServer:
         overrides the server's engine knobs for this call only.  The
         result carries the engine stats dict and the index ``generation``
         it was served on — after a mutation, results from the old snapshot
-        are distinguishable by that field alone."""
+        are distinguishable by that field alone.
+
+        ``stats["spans"]`` holds the call's ``server/search`` span with the
+        engine's own spans under it."""
         eng = self.opts if opts is None else resolve_engine_opts(opts)
         q = self._prep(queries)
         if kind == "range":
             if t is None:
                 raise ValueError("range search needs t= (a metric distance)")
-            t0 = now()
-            if self.index_kind == "forest":
-                hits, s = forest_range_search(
-                    self.index, q, float(t), self.forest_mechanism, opts=eng,
-                )
-            else:
-                hits, s = flat_index.bss_query_batched(
-                    self.index, q, float(t), opts=eng,
-                )
-            self._account(len(q), s, t0)
-            return SearchResult(
-                kind="range", hits=hits, stats=s,
-                generation=int(s.get("generation", 0)),
-            )
-        if kind == "knn":
+        elif kind == "knn":
             if self.index_kind == "forest":
                 raise NotImplementedError(FOREST_KNN_ERROR)
             if k is None or int(k) <= 0:
                 raise ValueError(f"knn search needs a positive k, got {k}")
-            t0 = now()
-            idx, dists, s = flat_index.bss_knn_batched(
-                self.index, q, int(k), r0=r0, max_rounds=max_rounds,
-                opts=eng,
-            )
-            self._account(len(q), s, t0)
-            return SearchResult(
-                kind="knn", indices=idx, distances=dists, stats=s,
-                generation=int(s.get("generation", 0)),
-            )
-        raise ValueError(f"kind must be range|knn, got {kind!r}")
+        else:
+            raise ValueError(f"kind must be range|knn, got {kind!r}")
+        log = SpanLog()
+        with log.span("server/search", kind=kind, n=len(q)) as call:
+            if kind == "knn":
+                idx, dists, s = flat_index.bss_knn_batched(
+                    self.index, q, int(k), r0=r0, max_rounds=max_rounds,
+                    opts=eng,
+                )
+                out = SearchResult(kind="knn", indices=idx, distances=dists)
+            elif self.index_kind == "forest":
+                hits, s = forest_range_search(
+                    self.index, q, float(t), self.forest_mechanism, opts=eng,
+                )
+                out = SearchResult(kind="range", hits=hits)
+            else:
+                hits, s = flat_index.bss_query_batched(
+                    self.index, q, float(t), opts=eng,
+                )
+                out = SearchResult(kind="range", hits=hits)
+            log.adopt(s.get("spans", ()))
+            self._account(len(q), s, call.start)
+        s["spans"] = log.records
+        out.stats = s
+        out.generation = int(s.get("generation", 0))
+        return out
 
     def range_query(self, user_embeddings: np.ndarray, min_score: float):
         """All items with dot-score >= min_score — exact, one fused pass.

@@ -13,6 +13,7 @@ exposition round-trip, and the recompile counter.
 from __future__ import annotations
 
 import json
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -699,22 +700,40 @@ def test_validate_trace_flags_problems():
     assert len(problems) >= 3
 
 
+def _xplane_host_events(trace_dir) -> list:
+    """(name, start_ns, duration_ns) of every event on the host planes of
+    the one ``.xplane.pb`` a ``jax.profiler.trace`` wrote under
+    ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = list(Path(trace_dir).rglob("*.xplane.pb"))
+    prof = ProfileData.from_file(str(path))
+    return [(ev.name, ev.start_ns, ev.duration_ns)
+            for plane in prof.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
 def test_front_trace_export_end_to_end(tmp_path):
-    """The tentpole acceptance: a serving run (with ``profile_dir=``, so
-    engine dispatches are also wrapped in jax-profiler annotations)
-    exports a Perfetto-loadable trace holding the admit->demux request
-    spans, the driver's dispatch phase slices, and the index mutation
-    events — all on the one serving clock."""
+    """End to end: a serving run, wrapped in a
+    ``jax.profiler.trace`` of the caller's own, exports a
+    Perfetto-loadable trace holding the admit->demux request spans, the
+    driver's dispatch phase slices, and the index mutation events — all
+    on the one serving clock — and the profiler's host plane holds the
+    program's own dispatch and engine spans, with no profiler session
+    opened by the front."""
+    import jax
+
     idx, db, q, t = _bss_built()
     prof = tmp_path / "prof"
-    with ServingFront(idx, buckets=(8,), max_delay_s=0.01, cache_size=4,
-                      profile_dir=str(prof)) as front:
-        r1 = front.submit(q[0], "range", t=t).result(timeout=120)
-        ms = front.append(_space("l2", 64, seed=6))
-        r2 = front.submit(q[1], "knn", k=3).result(timeout=120)
-        front.compact()
-        r3 = front.submit(q[2], "range", t=t).result(timeout=120)
-        path = front.export_trace(tmp_path / "trace.json")
+    with jax.profiler.trace(str(prof)):
+        with ServingFront(idx, buckets=(8,), max_delay_s=0.01,
+                          cache_size=4) as front:
+            r1 = front.submit(q[0], "range", t=t).result(timeout=120)
+            ms = front.append(_space("l2", 64, seed=6))
+            r2 = front.submit(q[1], "knn", k=3).result(timeout=120)
+            front.compact()
+            r3 = front.submit(q[2], "range", t=t).result(timeout=120)
+            path = front.export_trace(tmp_path / "trace.json")
 
     payload = load_trace(path)
     assert validate_trace(payload) == []
@@ -742,8 +761,19 @@ def test_front_trace_export_end_to_end(tmp_path):
                     and e["tid"] == int(r2.trace_id[1:]))
     assert r1_demux["ts"] + r1_demux["dur"] <= append_ev["ts"] + 1.0
     assert append_ev["ts"] + append_ev["dur"] <= r2_queue["ts"] + 1.0
-    # the jax profiler actually ran around the dispatches
-    assert prof.exists() and any(prof.rglob("*"))
+    # the dispatch/* events are the batch records' spans, stamp for stamp
+    rec = dict((name, (start, end)) for name, start, end, _ in r1.batch.spans)
+    ev = next(e for e in evs if e["name"] == "dispatch/engine"
+              and e["ts"] == pytest.approx(rec["dispatch/engine"][0] * 1e6))
+    assert ev["dur"] == pytest.approx(
+        (rec["dispatch/engine"][1] - rec["dispatch/engine"][0]) * 1e6)
+    # the caller's profiler session saw the program's own spans
+    host = {name for name, _, _ in _xplane_host_events(prof)}
+    assert {"dispatch", "dispatch/assemble", "dispatch/engine",
+            "dispatch/demux", "engine/range/launch", "engine/range/device",
+            "engine/range/d2h", "engine/range/select", "engine/knn/bounds",
+            "engine/knn/round"} <= host
+    assert not any(name.startswith("serve/engine") for name in host)
 
 
 def test_explain_and_spans_survive_generation_swap():
@@ -774,3 +804,291 @@ def test_explain_and_spans_survive_generation_swap():
         assert int(r.trace_id[1:]) in tids
     # generation swaps were real: results were served on three snapshots
     assert (r1.generation, r2.generation, r3.generation) == (0, 1, 2)
+
+
+# ------------------------------------------ host spans, copies and compiles
+
+
+def _sparse_built():
+    """A 2-d l2 corpus whose bound leaves ~6% of (query, block) cells
+    alive at its radius: the adaptive engine takes its cell-gather
+    realisation there."""
+    rng = np.random.default_rng(61)
+    x = rng.random((3016, 2)).astype(np.float32)
+    db, q = x[:3000], x[3000:]
+    idx = flat_index.build_bss("l2", db, n_pivots=8, n_pairs=10,
+                               block=64, seed=5)
+    t = _snap(pairwise_np("l2", q, db), 0.005)
+    return idx, db, q, t
+
+
+_RANGE_PATHS = {
+    "jnp-dense-fp32": (_bss_built, EngineOpts(realisation="dense")),
+    "jnp-cells-fp32": (_sparse_built, EngineOpts()),
+    "jnp-dense-bf16": (_bss_built,
+                       EngineOpts(realisation="dense", precision="bf16")),
+    "jnp-cells-bf16": (_sparse_built, EngineOpts(precision="bf16")),
+    "pallas-fp32": (_bss_built, EngineOpts(backend="pallas",
+                                           interpret=True)),
+}
+
+
+def _children(spans, i):
+    return [s[0] for s in spans if s[3] == i]
+
+
+@pytest.mark.parametrize("path", sorted(_RANGE_PATHS))
+def test_range_spans_nest_inside_the_call(path):
+    """Every host path of the range engine records its phases: they nest
+    (each inside its parent), lie inside the call's own interval on the
+    serving clock, keep device time apart from the copies, and leave the
+    answers equal to the oracle's."""
+    from repro.serve.queue import now
+
+    built, opts = _RANGE_PATHS[path]
+    idx, db, q, t = built()
+    a = now()
+    hits, stats = flat_index.bss_query_batched(idx, q, t, opts=opts)
+    b = now()
+    check_stats(stats)  # parents hold their children, start <= end
+    spans = stats["spans"]
+    assert all(a <= s[1] <= s[2] <= b for s in spans)
+    roots = [s[0] for s in spans if s[3] is None]
+    assert set(roots) <= {"engine/range/launch", "engine/range/device",
+                          "engine/range/d2h", "engine/range/select",
+                          "engine/range/stats"}
+    assert roots[0] == "engine/range/launch"
+    assert roots[-2:] == ["engine/range/select", "engine/range/stats"]
+    # every copy follows a wait for the device (or another copy of the
+    # arrays it waited for), never overlapping it
+    for i, s in enumerate(spans):
+        if s[0] == "engine/range/d2h" and s[3] is None:
+            prev = spans[i - 1]
+            assert prev[0] in ("engine/range/device", "engine/range/d2h")
+            assert prev[2] <= s[1]
+    ref, _ = flat_index.bss_query(idx, q, t)
+    assert hits == ref, path
+    if "cells" in path:  # the sparse realisation really ran
+        assert (flat_index.bss_lower_bounds(idx, q) <= t).mean() \
+            <= flat_index._DENSE_ALIVE_FRAC
+
+
+@pytest.mark.parametrize("opts", [
+    EngineOpts(realisation="dense"),
+    EngineOpts(realisation="dense", precision="bf16"),
+    EngineOpts(),
+], ids=["dense-fp32", "dense-bf16", "adaptive-fp32"])
+def test_knn_spans_one_round_span_per_round(opts):
+    from repro.serve.queue import now
+
+    idx, db, q, t = _sparse_built()
+    a = now()
+    ids, dists, stats = flat_index.bss_knn_batched(idx, q, 5, opts=opts)
+    b = now()
+    check_stats(stats)
+    spans = stats["spans"]
+    assert all(a <= s[1] <= s[2] <= b for s in spans)
+    rounds = [i for i, s in enumerate(spans) if s[0] == "engine/knn/round"]
+    assert len(rounds) == stats["rounds"] >= 1
+    roots = [s[0] for s in spans if s[3] is None]
+    assert roots == (["engine/knn/bounds"]
+                     + ["engine/knn/round"] * stats["rounds"]
+                     + ["engine/knn/stats"])
+    for i in rounds:
+        kids = _children(spans, i)
+        assert set(kids) == {"engine/knn/device", "engine/knn/d2h",
+                             "engine/knn/schedule"}, kids
+        assert kids[-1] == "engine/knn/schedule"
+    assert _children(spans, 0) == ["engine/knn/device", "engine/knn/d2h"]
+    # answers: the exact k nearest by brute force
+    d = pairwise_np("l2", q, db)
+    for i in range(len(q)):
+        assert set(ids[i].tolist()) == set(np.argsort(d[i])[:5].tolist())
+
+
+def test_d2h_bytes_are_the_copied_arrays():
+    """``d2h_bytes`` is the summed ``nbytes`` of exactly the device
+    arrays each path copies, reckoned here from their shapes."""
+    idx, db, q, t = _bss_built()
+    nq, nb, npad = len(q), idx.n_blocks, idx.n_blocks * idx.block
+    qt = -(-nq // flat_index._DEFAULT_BQ)
+    masks = nq * nb + qt * nb  # alive (Q, B) and tile_mask bools
+    # jnp dense: lb (Q, B) f32, the (Q, N) hit bitmask, the tile mask
+    _, s = flat_index.bss_query_batched(idx, q, t, opts=_DENSE)
+    assert s["d2h_bytes"] == 4 * nq * nb + nq * npad + qt * nb
+    # fused (Pallas, interpreted): dist (Q, N) f32 and both masks
+    _, s = flat_index.bss_query_batched(
+        idx, q, t, opts=EngineOpts(backend="pallas", interpret=True))
+    assert s["d2h_bytes"] == 4 * nq * npad + masks
+    # bf16 dense: hit (Q, N) bool, masks, recheck_tiles i32, band (Q,) i32
+    _, s = flat_index.bss_query_batched(
+        idx, q, t, opts=EngineOpts(realisation="dense", precision="bf16"))
+    assert s["d2h_bytes"] == nq * npad + masks + 4 + 4 * nq
+    # kNN dense: lb once; per round ids i32 + dists f32 (Q, k), kth f32,
+    # done bool, alive and tile masks
+    k = 4
+    _, _, s = flat_index.bss_knn_batched(idx, q, k, opts=_DENSE)
+    per_round = 8 * nq * k + 4 * nq + nq + masks
+    assert s["d2h_bytes"] == 4 * nq * nb + s["rounds"] * per_round
+    # the registry counter carries the bytes to the exposition
+    reg = MetricsRegistry()
+    fold_engine_stats(reg, s)
+    snap = reg.snapshot()["counters"]
+    assert snap["engine/d2h_bytes{engine=bss,kind=knn}"] == s["d2h_bytes"]
+    assert "engine/d2h_bytes" in METRIC_NAMES
+
+
+def test_compiles_count_fresh_shapes_only():
+    """A shape no call has used compiles (``stats["compiles"]`` names
+    the jit); the same call again compiles nothing.  The front polls the
+    same list of jits."""
+    data = _space("l2", 731, seed=71)  # sizes no other test uses
+    idx = flat_index.build_bss("l2", data[:700], n_pivots=8, n_pairs=10,
+                               block=64, seed=5)
+    q = data[700:]
+    t = _snap(pairwise_np("l2", q, data[:700]), 0.04)
+    _, s1 = flat_index.bss_query_batched(idx, q, t, opts=_DENSE)
+    _, s2 = flat_index.bss_query_batched(idx, q, t, opts=_DENSE)
+    _, _, k1 = flat_index.bss_knn_batched(idx, q, 3, opts=_DENSE)
+    _, _, k2 = flat_index.bss_knn_batched(idx, q, 3, opts=_DENSE)
+    if jit_cache_size(flat_index._dense_hit_mask_jit) < 0:
+        pytest.skip("this jax exposes no jit cache hook")
+    assert sum(s1["compiles"].values()) > 0 and "range/dense" in s1["compiles"]
+    assert sum(k1["compiles"].values()) > 0 and "knn/round" in k1["compiles"]
+    assert s2["compiles"] == {} and k2["compiles"] == {}
+    assert set(s1["compiles"]) <= set(flat_index.ENGINE_JITS)
+    front = ServingFront(idx, start=False)
+    assert front._compile_watch is flat_index.ENGINE_JITS
+
+
+def test_front_rows_share_one_batch_record():
+    """Rows of one micro-batch share ONE frozen record (not copies);
+    another batch gets another id.  The record's spans are the
+    dispatch's: ``dispatch`` holding assemble, engine (with the engine's
+    spans under it) and demux."""
+    import dataclasses
+
+    idx, db, q, t = _bss_built()
+    with ServingFront(idx, buckets=(8,), max_delay_s=0.2) as front:
+        first = [f.result(timeout=120) for f in
+                 [front.submit(qv, "range", t=t) for qv in q[:5]]]
+        second = [f.result(timeout=120) for f in
+                  [front.submit(qv, "knn", k=3) for qv in q[5:8]]]
+    by_id: dict = {}
+    for r in first + second:
+        by_id.setdefault(r.batch.id, []).append(r)
+    for rows in by_id.values():
+        assert all(r.batch is rows[0].batch for r in rows)
+    assert {r.batch.id for r in first}.isdisjoint(
+        {r.batch.id for r in second})
+    assert any(len(rows) > 1 for rows in by_id.values())
+    b = first[0].batch
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        b.id = 0
+    spans = b.spans
+    assert spans[0][0] == "dispatch" and spans[0][3] is None
+    assert _children(spans, 0) == ["dispatch/assemble", "dispatch/engine",
+                                   "dispatch/demux"]
+    eng = next(i for i, s in enumerate(spans) if s[0] == "dispatch/engine")
+    assert all(spans[i][3] is not None for i, s in enumerate(spans)
+               if s[0].startswith("engine/"))
+    assert {s[0] for s in spans if s[3] == eng} >= {
+        "engine/range/launch", "engine/range/select"}
+    check_stats({**_stats_core(), "spans": spans})
+    assert isinstance(b.d2h_bytes, int) and b.d2h_bytes > 0
+    assert isinstance(b.compiles, dict)
+    # engine_s is the dispatch/engine span
+    e = spans[eng]
+    assert first[0].engine_s == pytest.approx(e[2] - e[1])
+
+
+def _stats_core() -> dict:
+    """The smallest stats dict the schema accepts, for checking the
+    optional keys alone."""
+    return {"schema": 1, "engine": "bss", "kind": "range",
+            "backend": "jnp", "precision": "fp32", "n_queries": 0,
+            "per_query_dists": np.zeros(0, np.int64),
+            "dists_per_query": 0.0, "excluded": {}}
+
+
+def test_schema_checks_the_host_keys():
+    ok = {**_stats_core(), "spans": [("a", 1.0, 3.0, None),
+                                     ("a/b", 1.5, 2.0, 0)],
+          "d2h_bytes": 12, "compiles": {"range/lb": 1}}
+    assert validate_stats(ok) == []
+    bad = [
+        {"spans": [("a", 2.0, 1.0, None)]},             # end before start
+        {"spans": [("a", 1.0, 2.0, None), ("b", 1.5, 2.5, 0)]},  # outside
+        {"spans": [("a", 1.0, 2.0, 1)]},                # parent not earlier
+        {"spans": [("a", 1.0, None, None)]},            # still open
+        {"spans": "a"},
+        {"d2h_bytes": -1}, {"d2h_bytes": 1.5},
+        {"compiles": {"f": -1}}, {"compiles": [1]},
+    ]
+    for extra in bad:
+        assert validate_stats({**_stats_core(), **extra}), extra
+
+
+def test_program_spans_share_the_profiler_clock(tmp_path):
+    """Under ``jax.profiler.trace`` a program span's recorded start, mapped
+    through an anchor annotation opened at a known ``now()``, lies within
+    1 ms of the same span's event in the ``.xplane.pb``."""
+    import jax
+
+    from repro.serve.queue import now
+
+    idx, db, q, t = _bss_built()
+    flat_index.bss_query_batched(idx, q, t, opts=_DENSE)  # compile first
+    with jax.profiler.trace(str(tmp_path)):
+        anchor_at = now()
+        with jax.profiler.TraceAnnotation("test/anchor"):
+            pass
+        _, stats = flat_index.bss_query_batched(idx, q, t, opts=_DENSE)
+        _, _, kstats = flat_index.bss_knn_batched(idx, q, 3, opts=_DENSE)
+    events = _xplane_host_events(tmp_path)
+    (anchor_ns,) = [s for name, s, _ in events if name == "test/anchor"]
+    off = anchor_at - anchor_ns * 1e-9
+    for name, spans in (("engine/range/select", stats["spans"]),
+                        ("engine/knn/bounds", kstats["spans"])):
+        recorded = next(s for s in spans if s[0] == name)
+        (ev,) = [(s, d) for n, s, d in events if n == name]
+        assert abs(ev[0] * 1e-9 + off - recorded[1]) < 1e-3, name
+        assert abs(ev[1] * 1e-9 - (recorded[2] - recorded[1])) < 1e-3
+
+
+def test_server_search_span_holds_the_engine_spans():
+    """``RetrievalServer.search`` roots the call's spans at
+    ``server/search``; the answers stay the oracle's."""
+    from repro.serve.retrieval import RetrievalServer
+
+    idx, db, q, t = _bss_built()
+    srv = RetrievalServer(db, metric="l2", n_pivots=8, n_pairs=10, block=64,
+                          seed=5, opts=_DENSE)
+    for kind, kw in (("range", {"t": t}), ("knn", {"k": 3})):
+        res = srv.search(q, kind, **kw)
+        check_stats(res.stats)
+        spans = res.stats["spans"]
+        assert spans[0][0] == "server/search" and spans[0][3] is None
+        assert all(s[3] is not None for s in spans[1:])
+        assert {s[0].split("/")[0] for s in spans[1:]} == {"engine"}
+        assert res.stats["d2h_bytes"] > 0
+    ref, _ = flat_index.bss_query(srv.index, q, t)
+    assert srv.search(q, "range", t=t).hits == ref
+
+
+def test_span_log_nests_and_adopts():
+    from repro.obs import SpanLog
+
+    inner = SpanLog()
+    with inner.span("engine/x/a", n=1):
+        with inner.span("engine/x/b"):
+            pass
+    log = SpanLog()
+    with log.span("outer") as outer:
+        with log.span("outer/engine"):
+            log.adopt(inner.records)
+    names = [(r[0], r[3]) for r in log.records]
+    assert names == [("outer", None), ("outer/engine", 0),
+                     ("engine/x/a", 1), ("engine/x/b", 2)]
+    assert log.records[0][1:3] == (outer.start, outer.end)
+    assert all(r[2] is not None for r in log.records)
