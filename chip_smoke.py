@@ -299,17 +299,19 @@ def _bss_front_phases(name: str, metric: str, corpus, queries, seed: int,
 
 
 def _dense_copy_time(index, queries, ts: np.ndarray) -> None:
-    """Wall time of the dense range path's device->host copy of the
-    (Q, n_pad) fp32 distance matrix (a measured cost for the benchmark)."""
+    """Wall time of the device->host copy of the (Q, n_pad) fp32 distance
+    matrix, which the range path makes only when a call's hits outnumber
+    its compaction slots (a measured cost for the benchmark)."""
     import jax.numpy as jnp
 
     from repro.core import flat_index
 
     q = jnp.asarray(queries, jnp.float32)
     t_vec = jnp.asarray(ts, jnp.float32)
-    dist, _, _ = flat_index._query_batched_jit(
+    *_, dist = flat_index._query_batched_jit(
         "l2", q, t_vec, index.device, block=index.block,
         bq=flat_index._DEFAULT_BQ, backend="pallas", interpret=None,
+        cap=flat_index._compact_cap(q.shape[0]),
     )
     dist.block_until_ready()
     t0 = time.perf_counter()

@@ -723,8 +723,10 @@ def _dense_hit_mask_jit(
     block survival.  ``t`` is the (Q,) per-query radius vector (a negative
     entry, e.g. a serving-front padding row, hits nothing).  Bools are 4x
     cheaper than the distances to move, and position extraction is a single
-    host ``np.nonzero`` over the mask (XLA's sized ``nonzero`` costs seconds
-    at this size; numpy's scan is milliseconds)."""
+    host ``np.nonzero`` over the mask.  This serves the jnp backend, off the
+    chip; on a TPU the fp32 Pallas path compacts its hits on the device
+    instead, in two levels (:func:`_compact_hits`), and never runs one
+    sized ``nonzero`` over Q x N."""
     nq = queries.shape[0]
     if metric_name == "l2":
         qf = queries.astype(jnp.float32)
@@ -746,9 +748,115 @@ def _dense_hit_mask_jit(
     return hit.reshape(nq, -1)
 
 
+# Hit slots of one fused range call: 256 per padded query row, and never
+# fewer than 32,768, because one real row of a small bucket can hold all of
+# the batch's hits (sift-128 at its 1e-4 radius: 6,709 in one query).  A
+# call with more hits than slots selects from its dense distances instead.
+_COMPACT_CAP_FLOOR = 32_768
+_COMPACT_CAP_PER_ROW = 256
+# Hit slots filled per step of the compaction loop, which runs only as many
+# steps as the call's hits need.
+_COMPACT_CHUNK = 4096
+# Lane width of the cumulative-count rows the compaction searches.
+_LANES = 128
+
+
+def _compact_cap(nq: int) -> int:
+    """Hit slots of a fused range call over ``nq`` padded query rows."""
+    return max(_COMPACT_CAP_FLOOR, _COMPACT_CAP_PER_ROW * nq)
+
+
+def _running_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive running sum along the last axis of a (rows, w) array of
+    small non-negative ints, as a matmul with a triangular 0/1 matrix:
+    exact in float32 while a row sums below 2**24.  For a v5e, a cumsum
+    along 128 lanes or over a whole batch's cells compiles in seconds;
+    this compiles in a fraction of one."""
+    w = x.shape[-1]
+    upper = (jnp.arange(w)[:, None] <= jnp.arange(w)[None, :]).astype(
+        jnp.float32)
+    return jnp.dot(x.astype(jnp.float32), upper,
+                   precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+
+
+def _compact_hits(dist: jnp.ndarray, t: jnp.ndarray, *, block: int,
+                  cap: int):
+    """Compact the hits ``dist <= t[:, None]`` of a (Q, n_pad) distance
+    matrix.  Returns (counts (Q,) int32, pos (cap,) int32): each query's
+    hit count, and the hit positions in row-major (query, position) order
+    — the oracle's order — with -1 in the unused tail.  When the hits
+    outnumber ``cap``, ``pos`` is all -1 and the caller selects from
+    ``dist`` itself.
+
+    Two levels, so no search ever runs over Q x n_pad: per (query, block)
+    cell a hit count and their running sum ``cs``, laid out 128 cells to a
+    row; then, for each hit slot k, the cell holding it is the number of
+    running sums <= k (a compare against the last sum of each row, then
+    within the one row it lands in), and its lane within the cell is found
+    from that cell's own ``block`` distances.  Slots are filled
+    ``_COMPACT_CHUNK`` at a time, for as many steps as there are hits."""
+    nq, n_pad = dist.shape
+    nb = n_pad // block
+    n_cells = nq * nb
+    # summed over a (Q/8, 8, B, block) view where Q allows: that view is
+    # the TPU's own (8, 128) tiling of the matrix, so it is not copied
+    sub = 8 if nq % 8 == 0 else 1
+    cnt = jnp.sum(
+        (dist <= t[:, None]).reshape(nq // sub, sub, nb, block), axis=3,
+        dtype=jnp.int32,
+    ).reshape(nq, nb)
+    counts = jnp.sum(cnt, axis=1, dtype=jnp.int32)
+    # the padding cells count 0, so the padded tail repeats the total
+    n_rows = -(-n_cells // _LANES)
+    in_row = _running_sum(jnp.pad(
+        cnt.reshape(-1), (0, n_rows * _LANES - n_cells)
+    ).reshape(n_rows, _LANES))
+    row_last = jnp.cumsum(in_row[:, -1], dtype=jnp.int32)
+    cs_rows = in_row + (row_last - in_row[:, -1])[:, None]
+    total = row_last[-1]
+    lanes = jnp.arange(_LANES, dtype=jnp.int32)
+    chunk = _COMPACT_CHUNK
+    width = -(-cap // chunk) * chunk
+
+    def fill(state):
+        k0, pos = state
+        k = k0 + jnp.arange(chunk, dtype=jnp.int32)  # hit slots
+        # cell r = #{i : cs[i] <= k}; below `total` it lies in row `sr`
+        sr = jnp.minimum(
+            jnp.sum(row_last[None, :] <= k[:, None], axis=1,
+                    dtype=jnp.int32),
+            n_rows - 1,
+        )
+        row = cs_rows[sr]  # (chunk, 128)
+        c = jnp.minimum(
+            jnp.sum(row <= k[:, None], axis=1, dtype=jnp.int32), _LANES - 1
+        )
+        cs_r = jnp.sum(jnp.where(lanes[None, :] == c[:, None], row, 0),
+                       axis=1)
+        r = jnp.minimum(sr * _LANES + c, n_cells - 1)
+        q, b = r // nb, r % nb
+        cell = jax.vmap(
+            lambda qi, bi: jax.lax.dynamic_slice(
+                dist, (qi, bi * block), (1, block))[0]
+        )(q, b)
+        run = _running_sum(cell <= t[q][:, None])  # (chunk, block)
+        rank = k - (cs_r - run[:, -1])  # the slot's rank inside its cell
+        lane = jnp.sum(run <= rank[:, None], axis=1, dtype=jnp.int32)
+        p = jnp.where(k < total, b * block + lane, -1)
+        return k0 + chunk, jax.lax.dynamic_update_slice(pos, p, (k0,))
+
+    _, pos = jax.lax.while_loop(
+        lambda s: (s[0] < total) & (total <= cap),
+        fill,
+        (jnp.int32(0), jnp.full(width, -1, jnp.int32)),
+    )
+    return counts, pos[:cap]
+
+
 @partial(
     jax.jit,
-    static_argnames=("metric_name", "block", "bq", "backend", "interpret"),
+    static_argnames=("metric_name", "block", "bq", "backend", "interpret",
+                     "cap"),
 )
 def _query_batched_jit(
     metric_name: str,
@@ -760,15 +868,18 @@ def _query_batched_jit(
     bq: int,
     backend: str,
     interpret: bool | None,
+    cap: int,
 ):
-    """One fused range-search pass.  Returns (dist (Q, n_pad), alive (Q, B),
-    tile_mask (Qtiles, B)).
+    """One fused range-search pass with its hits compacted on the device.
+    Returns (counts (Q,), pos (cap,), alive (Q, B), tile_mask (Qtiles, B),
+    dist (Q, n_pad)); ``counts`` and ``pos`` are :func:`_compact_hits`'s.
 
     ``t`` is the (Q,) per-query radius vector.  dist is +inf wherever the
     planar bound excluded the cell (or padding); every finite entry is an
     exact metric distance.  Exactness: a tile survives when ANY of its
     queries has lb <= its own t, so no true hit of any query is ever pruned
-    (per-query hits are re-filtered by d <= t on the host)."""
+    (per-query hits are re-filtered by d <= t).  The caller reads ``dist``
+    only when the hits outnumber ``cap``."""
     lb = _fused_lower_bounds(
         metric_name, queries, dev.pivots, dev.pairs, dev.deltas, dev.boxes,
         backend=backend, bq=bq, interpret=interpret,
@@ -779,7 +890,8 @@ def _query_batched_jit(
         metric_name, queries, dev.data, dev.valid, tile_mask,
         backend=backend, block=block, bq=bq, interpret=interpret,
     )
-    return dist, alive, tile_mask
+    counts, pos = _compact_hits(dist, t, block=block, cap=cap)
+    return counts, pos, alive, tile_mask, dist
 
 
 @partial(
@@ -1027,12 +1139,15 @@ def bss_query_batched(
     which the test suite enforces at safe thresholds.
 
     The ``pallas`` backend runs the dense masked kernel (tile skipping on
-    TPU, interpret mode in tests).  The ``jnp`` backend picks its exact
-    phase by survivor density: below ``_DENSE_ALIVE_FRAC`` only the alive
-    (query, block) cells are gathered (``_cells_exact_jit``); above it one
-    dense per-query-masked pass wins (``_dense_hit_mask_jit``).  Either
-    way only compact hits / a bitmask cross back to the host — never the
-    distance matrix.
+    TPU, interpret mode in tests) and compacts its hits on the device
+    (:func:`_compact_hits`); only a call with more hits than
+    :func:`_compact_cap` slots copies the distance matrix and selects on
+    the host (``stats["compact_overflow"]`` is then 1).  The ``jnp``
+    backend picks its exact phase by survivor density: below
+    ``_DENSE_ALIVE_FRAC`` only the alive (query, block) cells are gathered
+    (``_cells_exact_jit``); above it one dense per-query-masked pass wins
+    (``_dense_hit_mask_jit``).  Either way only compact hits / a bitmask
+    cross back to the host — never the distance matrix.
 
     A mesh-built index (``build_bss(mesh=...)``) serves through the sharded
     engine — one shard-local fused pass per device, hit bitmasks
@@ -1128,8 +1243,9 @@ def bss_query_batched(
         return results, call.finish(
             _finish_stats(stats, kind="range", backend=backend)
         )
+    cap = _compact_cap(nq)
     with call.log.span("engine/range/launch"):
-        outs = _query_batched_jit(
+        *outs, dist = _query_batched_jit(
             metric_eng,
             jnp.asarray(queries),
             jnp.asarray(t_vec),
@@ -1138,18 +1254,26 @@ def bss_query_batched(
             bq=bq,
             backend=backend,
             interpret=interpret,
+            cap=cap,
         )
-    dist, alive, tile_mask = call.fetch(*outs)
+    counts, pos, alive, tile_mask = call.fetch(*outs)
+    n_hits = int(counts.sum())
+    overflow = n_hits > cap
+    if overflow:  # more hits than slots: select from the dense distances
+        dist = call.fetch(dist)
     with call.log.span("engine/range/select"):
-        hit = dist <= t_vec[:, None]
-        qidx, pidx = np.nonzero(hit)  # row-major: pidx ascending within a query
+        if overflow:
+            # row-major: positions ascending within a query
+            pidx = np.nonzero(dist <= t_vec[:, None])[1]
+        else:
+            pidx = pos[:n_hits]
         orig = index.perm[pidx]
-        counts = hit.sum(axis=1)
         per_query = np.split(orig, np.cumsum(counts)[:-1])
         results = [r.tolist() for r in per_query]
     with call.log.span("engine/range/stats"):
         stats = _batched_stats(index, alive, tile_mask)
         stats["precision"] = "fp32"
+        stats["compact_overflow"] = int(overflow)
     return results, call.finish(
         _finish_stats(stats, kind="range", backend=backend)
     )

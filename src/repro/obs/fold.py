@@ -93,6 +93,11 @@ def fold_engine_stats(reg: MetricsRegistry, stats: dict) -> None:
 
     if "d2h_bytes" in stats:
         reg.counter("engine/d2h_bytes", **lbl).inc(int(stats["d2h_bytes"]))
+    if "compact_overflow" in stats:
+        # range calls whose hits outnumbered the device compaction's slots
+        reg.counter("engine/range/compact_overflow", **lbl).inc(
+            int(stats["compact_overflow"])
+        )
 
     if kind == "knn" and "rounds" in stats:
         reg.histogram("engine/knn_rounds", **lbl).observe(

@@ -36,6 +36,9 @@ Optional keys, type-checked when present — the host side of the call
                         the host
 ``compiles``            dict jit name -> int — new compile-cache entries
                         during the call, for each engine jit that gained any
+``compact_overflow``    int 0 or 1 — the BSS fp32 Pallas range call found
+                        more hits than its device-side compaction has slots,
+                        and selected them from the dense distances instead
 ======================  =====================================================
 
 Engine-specific keys (``n_blocks``, ``tiles_computed``, ``n_levels``,
@@ -98,6 +101,7 @@ METRIC_NAMES = {
     "engine/recheck_tiles",
     "engine/knn_rounds",
     "engine/d2h_bytes",
+    "engine/range/compact_overflow",
     # sharded-engine work split (fold_engine_stats on sharded stats)
     "shard/dists",
     "shard/blocks",
@@ -246,6 +250,12 @@ def validate_stats(stats) -> list:
             problems.append(
                 f"compiles={c!r} is not a dict of name -> non-negative int"
             )
+    if "compact_overflow" in stats and not (
+        _is_count(stats["compact_overflow"]) and stats["compact_overflow"] <= 1
+    ):
+        problems.append(
+            f"compact_overflow={stats['compact_overflow']!r} is not 0 or 1"
+        )
     return problems
 
 

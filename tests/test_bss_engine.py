@@ -110,6 +110,78 @@ def test_range_all_and_none_excluded(t, expect_all):
         assert sb["block_exclusion_rate"] == 1.0
 
 
+def _mixed_radii(metric, nq):
+    """An index, queries, and per-query radii that mix padding rows (t < 0),
+    rows with no hit, rows with a few hits and rows with many, each radius
+    snapped to a gap of its own query's distances."""
+    data = _space(metric, 600 + nq, 10, seed=nq)
+    db, q = data[:600], data[600:]
+    idx = flat_index.build_bss(metric, db, n_pivots=6, n_pairs=8,
+                               block=128, seed=4)
+    d = pairwise_np(metric, q, db)
+    t = np.empty(nq, np.float32)
+    for i in range(nq):
+        t[i] = (-1.0, 0.5 * d[i].min(), safe_threshold(d[i], 0.02),
+                safe_threshold(d[i], 0.6))[i % 4]
+    return idx, q, t
+
+
+def _compact_d2h(idx, nq, cap):
+    """Bytes the compacted Pallas range call copies: counts (Q,) and
+    pos (cap,) int32, alive (Q, B) and tile_mask (Qtiles, B) bools."""
+    qt = -(-nq // _PALLAS.bq)
+    return 4 * nq + 4 * cap + nq * idx.n_blocks + qt * idx.n_blocks
+
+
+@pytest.mark.parametrize("metric,nq", [("l2", 24), ("jsd", 21)])
+def test_pallas_range_compacts_hits_on_device(metric, nq, monkeypatch):
+    """The Pallas range path compacts its hits on the device: the hit lists
+    and their per-query order are the oracle's, the call copies only the
+    counts, the hit slots and the two masks, and a repeated call at the
+    same shape compiles nothing.  A small step makes the hits span several
+    steps of the compaction loop."""
+    monkeypatch.setattr(flat_index, "_COMPACT_CHUNK", 256)
+    flat_index._query_batched_jit.clear_cache()  # trace again at this step
+    idx, q, t = _mixed_radii(metric, nq)
+    oracle, _ = flat_index.bss_query(idx, q, t)
+    batched, stats = flat_index.bss_query_batched(idx, q, t, opts=_PALLAS)
+    assert batched == oracle
+    assert sum(map(len, batched)) > 256
+    assert any(t < 0) and all(not r for r, ti in zip(batched, t) if ti < 0)
+    assert stats["compact_overflow"] == 0
+    assert stats["d2h_bytes"] == _compact_d2h(
+        idx, nq, flat_index._compact_cap(nq))
+    again, stats2 = flat_index.bss_query_batched(idx, q, t, opts=_PALLAS)
+    assert again == oracle and stats2["compiles"] == {}
+
+
+@pytest.mark.parametrize("metric,nq", [("l2", 24), ("jsd", 21)])
+def test_pallas_range_overflow_selects_from_dense(metric, nq, monkeypatch):
+    """A call with more hits than compaction slots copies the dense
+    distances and selects on the host: identical hits and stats, with
+    ``compact_overflow`` 1 (0 for a call that fits)."""
+    idx, q, t = _mixed_radii(metric, nq)
+    oracle, _ = flat_index.bss_query(idx, q, t)
+    fit, s_fit = flat_index.bss_query_batched(idx, q, t, opts=_PALLAS)
+    monkeypatch.setattr(flat_index, "_COMPACT_CAP_FLOOR", 64)
+    monkeypatch.setattr(flat_index, "_COMPACT_CAP_PER_ROW", 0)
+    over, s_over = flat_index.bss_query_batched(idx, q, t, opts=_PALLAS)
+    assert sum(map(len, oracle)) > 64
+    assert fit == over == oracle
+    assert (s_fit["compact_overflow"], s_over["compact_overflow"]) == (0, 1)
+    npad = idx.n_blocks * idx.block
+    assert s_over["d2h_bytes"] == _compact_d2h(idx, nq, 64) + 4 * nq * npad
+    host = {"spans", "d2h_bytes", "compiles", "compact_overflow"}
+    assert set(s_fit) == set(s_over)
+    for k in set(s_fit) - host:
+        a, b = s_fit[k], s_over[k]
+        if isinstance(a, dict):
+            assert a.keys() == b.keys()
+            assert all(np.array_equal(a[m], b[m]) for m in a), k
+        else:
+            assert np.array_equal(a, b), k
+
+
 # --------------------------------------------------------------------- kNN
 
 

@@ -916,10 +916,12 @@ def test_d2h_bytes_are_the_copied_arrays():
     # jnp dense: lb (Q, B) f32, the (Q, N) hit bitmask, the tile mask
     _, s = flat_index.bss_query_batched(idx, q, t, opts=_DENSE)
     assert s["d2h_bytes"] == 4 * nq * nb + nq * npad + qt * nb
-    # fused (Pallas, interpreted): dist (Q, N) f32 and both masks
+    # fused (Pallas, interpreted): hit counts (Q,) and hit slots (cap,)
+    # i32 from the device compaction, and both masks
     _, s = flat_index.bss_query_batched(
         idx, q, t, opts=EngineOpts(backend="pallas", interpret=True))
-    assert s["d2h_bytes"] == 4 * nq * npad + masks
+    assert s["compact_overflow"] == 0
+    assert s["d2h_bytes"] == 4 * nq + 4 * flat_index._compact_cap(nq) + masks
     # bf16 dense: hit (Q, N) bool, masks, recheck_tiles i32, band (Q,) i32
     _, s = flat_index.bss_query_batched(
         idx, q, t, opts=EngineOpts(realisation="dense", precision="bf16"))
@@ -1027,6 +1029,26 @@ def test_schema_checks_the_host_keys():
     ]
     for extra in bad:
         assert validate_stats({**_stats_core(), **extra}), extra
+
+
+def test_compact_overflow_schema_and_counter():
+    """``compact_overflow`` is an optional 0-or-1 key of the schema, and
+    the registry counter ``engine/range/compact_overflow`` sums it over
+    calls (a call that fit adds 0, and still shows the counter)."""
+    for v in (0, 1, np.int64(1)):
+        assert validate_stats({**_stats_core(), "compact_overflow": v}) == []
+    for v in (2, -1, 0.5, True, "1", None):
+        assert validate_stats({**_stats_core(), "compact_overflow": v}), v
+    reg = MetricsRegistry()
+    for v in (0, 1, 0, 1, 1):
+        fold_engine_stats(reg, {**_stats_core(), "compact_overflow": v})
+    snap = reg.snapshot()["counters"]
+    assert snap["engine/range/compact_overflow{engine=bss,kind=range}"] == 3
+    reg2 = MetricsRegistry()
+    fold_engine_stats(reg2, {**_stats_core(), "compact_overflow": 0})
+    assert reg2.snapshot()["counters"][
+        "engine/range/compact_overflow{engine=bss,kind=range}"] == 0
+    assert "engine/range/compact_overflow" in METRIC_NAMES
 
 
 def test_program_spans_share_the_profiler_clock(tmp_path):

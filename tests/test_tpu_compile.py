@@ -125,3 +125,30 @@ def test_planar_lower_bound_kernel_compiles(one_chip):
         _sds(one_chip, (Q, N_PAIRS)), _sds(one_chip, (Q, N_PAIRS)),
         _sds(one_chip, (N_PAIRS,)), _sds(one_chip, (4, N_PAIRS, N_BLOCKS)),
     )
+
+
+@pytest.mark.parametrize("metric,nq,rows,dim", [
+    ("l2", 8, N_ROWS, 128),     # sift-128: the serving front's bucket 8
+    ("jsd", Q, 101_504, 112),   # colors under JSD: a 512-query batch
+])
+def test_fused_range_jit_compiles(one_chip, metric, nq, rows, dim):
+    """The fp32 Pallas range path as one program: the bound and masked
+    kernels, then the compaction of the hits on the device (its loop over
+    hit slots, row gathers and lane counts)."""
+    from repro.core import flat_index
+
+    nb = rows // 128
+    dev = flat_index.BSSDeviceArrays(
+        data=_sds(one_chip, (rows, dim)),
+        pivots=_sds(one_chip, (16, dim)),
+        pairs=_sds(one_chip, (N_PAIRS, 2), jnp.int32),
+        deltas=_sds(one_chip, (N_PAIRS,)),
+        boxes=_sds(one_chip, (4, N_PAIRS, nb)),
+        valid=_sds(one_chip, (rows,), jnp.bool_),
+    )
+    text = flat_index._query_batched_jit.lower(
+        metric, _sds(one_chip, (nq, dim)), _sds(one_chip, (nq,)), dev,
+        block=128, bq=128, backend="pallas", interpret=False,
+        cap=flat_index._compact_cap(nq),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text and "while" in text
